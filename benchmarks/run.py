@@ -10,6 +10,8 @@ import importlib
 import sys
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 MODULES = [
     "benchmarks.fig05_core_scaling",
     "benchmarks.fig06_latency_breakdown",
@@ -39,6 +41,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="substring filter on module names")
     args = ap.parse_args()
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for modname in MODULES:

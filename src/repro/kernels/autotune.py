@@ -15,18 +15,20 @@ Two scoring modes:
 * ``analytic`` — a deterministic roofline-style model (compute time at
   the MXU-utilization-discounted peak, HBM traffic including block
   revisits and padding waste, a small per-grid-step overhead), the only
-  meaningful mode on this CPU container and the one CI uses (seedable:
+  mode that runs without a chip and the one CI uses (seedable:
   same shapes -> same blocks, no timing noise);
-* ``measured`` — times the real kernel (``interpret=True`` off-TPU),
-  for refreshing the cache on actual hardware.
+* ``measured`` — times the compiled kernel on a TPU (and raises
+  without one), for refreshing the cache on actual hardware.
 
 Cache layout: two layers. The committed seed
 (``src/repro/kernels/tilings.json``, refreshed by ``make autotune``)
-ships tuned defaults for the repo's hot-path shapes; a user-writable
-overlay (``$REPRO_AUTOTUNE_CACHE`` or ``~/.cache/repro/autotune.json``)
-absorbs shapes tuned at runtime so a repo checkout never dirties
-itself. ``scripts/autotune.py --check`` asserts the committed seed is
-in sync with what the analytic sweep produces.
+ships tuned defaults for the repo's hot-path shapes; a miss runs the
+deterministic analytic sweep and memoizes it in-process. Only when
+``$REPRO_AUTOTUNE_CACHE`` (or an explicit ``path``) names a file is
+there a writable overlay that absorbs shapes tuned at runtime, so a run
+from a checkout depends on committed files alone.
+``scripts/autotune.py --check`` asserts the committed seed is in sync
+with what the analytic sweep produces.
 """
 from __future__ import annotations
 
@@ -51,8 +53,13 @@ SCHEMA_VERSION = 1
 # Coarse, but it is what makes "few big blocks" beat "many tiny blocks"
 # once both fit in VMEM and stream the same bytes.
 _GRID_STEP_S = 0.3e-6
-# Don't plan more than half of VMEM: double-buffering needs the rest.
-_VMEM_BUDGET = hw.VMEM_BYTES // 2
+# Plan tiles within Mosaic's default scoped VMEM: a kernel that asks for
+# no more compiles at any shape (a larger plan is refused by the chip's
+# compiler). The resize kernel's input block is a whole plane, so it
+# raises its own limit (kernels/resize.py) and is planned against half
+# the physical VMEM instead.
+_VMEM_BUDGET = hw.SCOPED_VMEM_BYTES
+_PLANE_VMEM_BUDGET = hw.VMEM_BYTES // 2
 
 _F32 = 4  # itemsize used for VMEM/HBM planning (accumulators are f32)
 
@@ -97,15 +104,14 @@ class TuneResult:
 # --------------------------------------------------------------------------
 
 class AutotuneCache:
-    """Seed (committed, read-only) + overlay (user-writable) JSON cache."""
+    """Seed (committed, read-only) + optional overlay (writable) JSON
+    cache; with no overlay, runtime tunings live in memory only."""
 
     def __init__(self, path: str | os.PathLike | None = None,
                  seed_path: str | os.PathLike | None = SEED_PATH):
-        env = os.environ.get("REPRO_AUTOTUNE_CACHE")
-        self.path = pathlib.Path(
-            path if path is not None else
-            env if env else
-            pathlib.Path.home() / ".cache" / "repro" / "autotune.json")
+        path = path if path is not None else \
+            os.environ.get("REPRO_AUTOTUNE_CACHE") or None
+        self.path = pathlib.Path(path) if path is not None else None
         self.seed_path = pathlib.Path(seed_path) if seed_path else None
         self._entries: dict[str, dict] | None = None
 
@@ -133,6 +139,8 @@ class AutotuneCache:
     def store(self, key: str, entry: dict) -> None:
         """Memoize + persist to the overlay (never the committed seed)."""
         self._load()[key] = entry
+        if self.path is None:
+            return
         try:
             self.path.parent.mkdir(parents=True, exist_ok=True)
             on_disk = {}
@@ -226,11 +234,14 @@ def _measure_matmul(M, K, N, dtype, blocks) -> float:
     import jax.numpy as jnp
 
     from repro.kernels import matmul as mm
-    interpret = jax.default_backend() != "tpu"
+    if jax.default_backend() != "tpu":
+        raise RuntimeError(
+            "measured autotuning times the compiled kernel and needs a "
+            f"TPU; this process has {jax.default_backend()!r}")
     key = jax.random.PRNGKey(0)
     a = jax.random.normal(key, (M, K), jnp.dtype(dtype))
     b = jax.random.normal(key, (K, N), jnp.dtype(dtype))
-    f = jax.jit(lambda a, b: mm.matmul(a, b, interpret=interpret, **blocks))
+    f = jax.jit(lambda a, b: mm.matmul(a, b, **blocks))
     f(a, b).block_until_ready()
     repeat = 3
     t0 = time.perf_counter()
@@ -278,12 +289,11 @@ def resize_key(H: int, W: int, out_h: int, out_w: int, dtype: str) -> str:
 
 
 def resize_candidates(H: int, W: int, out_h: int, out_w: int) -> list[int]:
+    from repro.kernels.resize import plane_vmem_bytes
     out = []
     for blk in _pow2s(8, 512):
         blk = min(blk, out_h)
-        # per-step VMEM: input plane + Ry block + Rx + out block (f32)
-        vmem = (H * W + blk * H + out_w * W + blk * out_w) * _F32 * 2
-        if vmem > _VMEM_BUDGET:
+        if plane_vmem_bytes(H, W, blk, out_w) > _PLANE_VMEM_BUDGET:
             continue
         if blk not in out:
             out.append(blk)

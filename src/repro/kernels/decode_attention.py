@@ -6,9 +6,12 @@ G query heads of a KV group are processed together as the (sublane) rows
 of one tile so the MXU sees a (G x D) @ (D x blk_k) matmul per tile
 instead of G vector products.
 
-Grid: (batch, kv_heads, kv_blocks). Validity (kv_len) and sliding-window
-masks are applied per tile; fully-invalid tiles are skipped before any
-VMEM compute via pl.when on the block index.
+Grid: (batch, kv_heads, kv_blocks). The (B,) ``kv_len`` vector is a
+scalar-prefetch operand: it lands in SMEM whole before the grid starts
+and each step reads its row's length by ``program_id``. Validity
+(kv_len) and sliding-window masks are applied per tile; fully-invalid
+tiles are skipped before any VMEM compute via pl.when on the block
+index.
 """
 from __future__ import annotations
 
@@ -58,7 +61,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    kv_len = len_ref[0]
+    kv_len = len_ref[pl.program_id(0)]
     first_k = ki * blk_k
     run = first_k < kv_len
     if window is not None:
@@ -113,23 +116,27 @@ def decode_attention(
 
     kern = functools.partial(_kernel, scale=scale, window=window,
                              blk_k=blk_k, n_blocks=n_blocks)
-    out = pl.pallas_call(
-        kern,
+    # index maps take the prefetched kv_len ref as a trailing argument
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, KV, n_blocks),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, j: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, blk_k, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, blk_k, Dv), lambda b, h, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, G, D), lambda b, h, j, _: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, blk_k, D), lambda b, h, j, _: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, blk_k, Dv), lambda b, h, j, _: (b, h, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, Dv), lambda b, h, j: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, Dv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, Dv),
+                               lambda b, h, j, _: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, Dv), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, Dv), q.dtype),
         interpret=interpret,
     )(kv_len.astype(jnp.int32), qt, kt, vt)
     return out.reshape(B, 1, H, Dv)

@@ -55,8 +55,11 @@ def _kernel(*refs, n_k_blocks: int, epilogue: str, has_bias: bool):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # HIGHEST: a float32 dot on the MXU otherwise rounds its operands
+    # to bf16, and the float32 contract (and the oracle) no longer holds
     acc_ref[...] += jax.lax.dot(
         a_ref[...].astype(jnp.float32), b_ref[...].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
     @pl.when(ki == n_k_blocks - 1)
@@ -111,7 +114,7 @@ def matmul(a: jax.Array, b: jax.Array, *, bias: jax.Array | None = None,
         out_specs=pl.BlockSpec((blk_m, blk_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), a.dtype),
         scratch_shapes=[pltpu.VMEM((blk_m, blk_n), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)

@@ -5,9 +5,10 @@ Every op has three implementations:
                  (small sizes only; ground truth for tests).
   * ``xla``    — a memory-bounded pure-JAX path (chunked / associative scans)
                  that lowers on any backend. This is what the multi-pod
-                 dry-run compiles, since Pallas-TPU kernels cannot lower on
-                 the CPU backend of this container.
-  * ``pallas`` — the Pallas TPU kernel (``interpret=True`` on CPU for tests).
+                 dry-run compiles on fake CPU devices.
+  * ``pallas`` — the Pallas TPU kernel, compiled by Mosaic. Tests run the
+                 same kernels on the CPU as ``pallas_interpret``, a choice
+                 only tests make.
 
 ``set_default_impl`` switches the default globally (models call these ops
 without an explicit ``impl=``).
@@ -408,7 +409,9 @@ def matmul(a: jax.Array, b: jax.Array, *, bias: jax.Array | None = None,
         return mm.matmul(a, b, bias=bias, epilogue=epilogue, **blocks,
                          interpret=(impl == "pallas_interpret"))
     # ref and xla coincide: XLA's dot is already the memory-optimal form
-    out = jnp.dot(a.astype(jnp.float32), b.astype(jnp.float32))
+    # (at HIGHEST, so the TPU keeps the float32 contract the kernel keeps)
+    out = jnp.dot(a.astype(jnp.float32), b.astype(jnp.float32),
+                  precision=jax.lax.Precision.HIGHEST)
     if bias is not None:
         out = out + bias.astype(jnp.float32)
     if epilogue == "tanh":

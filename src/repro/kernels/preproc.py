@@ -20,7 +20,8 @@ the three dense pre/post stages onto the accelerator:
     suppression scan itself is tiny and sequential and stays in the
     surrounding jitted program (:mod:`repro.preprocess.device`).
 
-All kernels take ``interpret=True`` on CPU (tests/this container).
+Tests run every kernel with ``interpret=True`` on the CPU; the program
+runs them compiled on the TPU.
 """
 from __future__ import annotations
 
@@ -33,34 +34,57 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.resize import plane_compiler_params
+
 
 # --------------------------------------------------------------------------
 # Planar YUV -> RGB (decode-emulation)
 # --------------------------------------------------------------------------
 
+def _as_f32(x):
+    # Mosaic has no direct uint8 -> float32 cast; via int32 is exact
+    if x.dtype == jnp.uint8:
+        x = x.astype(jnp.int32)
+    return x.astype(jnp.float32)
+
+
 def _yuv_kernel(yuv_ref, o_ref):
-    x = yuv_ref[0].astype(jnp.float32)            # (3, H, W)
+    x = _as_f32(yuv_ref[0])                       # (3, H, W)
     y = x[0]
     u = x[1] - 128.0
     v = x[2] - 128.0
     r = y + 1.402 * v
     g = y - 0.344136 * u - 0.714136 * v
     b = y + 1.772 * u
-    rgb = jnp.stack([r, g, b], axis=-1)           # (H, W, 3)
-    o_ref[0] = jnp.clip(jnp.round(rgb), 0.0, 255.0).astype(o_ref.dtype)
+    rgb = jnp.stack([r, g, b], axis=0)            # planar (3, H, W)
+    o_ref[0] = jnp.clip(jnp.round(rgb), 0.0, 255.0).astype(
+        jnp.int32).astype(o_ref.dtype)
+
+
+_YUV_BAND_ROWS = 64
 
 
 def yuv_to_rgb(yuv: jax.Array, *, interpret: bool = False) -> jax.Array:
-    """(B, 3, H, W) planar uint8 -> (B, H, W, 3) uint8 (BT.601 full)."""
+    """(B, 3, H, W) planar uint8 -> (B, H, W, 3) uint8 (BT.601 full).
+
+    The grid walks 64-row bands (a whole full-HD frame's float32 planes
+    outgrow VMEM; the last band may overhang and its extra rows are
+    dropped). The kernel writes planar RGB: an interleaved (H, W, 3)
+    block puts 3 on the 128-wide lane axis, which pads a frame past
+    VMEM. XLA interleaves the planes after the call.
+    """
     B, _, H, W = yuv.shape
-    return pl.pallas_call(
+    blk = min(_YUV_BAND_ROWS, H)
+    band = pl.BlockSpec((1, 3, blk, W), lambda b, i: (b, 0, i, 0))
+    planar = pl.pallas_call(
         _yuv_kernel,
-        grid=(B,),
-        in_specs=[pl.BlockSpec((1, 3, H, W), lambda b: (b, 0, 0, 0))],
-        out_specs=pl.BlockSpec((1, H, W, 3), lambda b: (b, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, W, 3), jnp.uint8),
+        grid=(B, pl.cdiv(H, blk)),
+        in_specs=[band],
+        out_specs=band,
+        out_shape=jax.ShapeDtypeStruct((B, 3, H, W), jnp.uint8),
         interpret=interpret,
     )(yuv)
+    return planar.transpose(0, 2, 3, 1)
 
 
 # --------------------------------------------------------------------------
@@ -70,16 +94,18 @@ def yuv_to_rgb(yuv: jax.Array, *, interpret: bool = False) -> jax.Array:
 def _letterbox_kernel(blk: int, top: int, ch: int, left: int, cw: int,
                       pad_value: float,
                       img_ref, ly_ref, lx_ref, sb_ref, o_ref):
-    img = img_ref[0].astype(jnp.float32)          # (H, W)
-    t = jax.lax.dot(ly_ref[...], img)             # (blk, W)
-    t = jax.lax.dot(t, lx_ref[...].T)             # (blk, out_w)
+    img = _as_f32(img_ref[0])                     # (H, W)
+    hi = jax.lax.Precision.HIGHEST                # float32 on the MXU
+    t = jax.lax.dot(ly_ref[...], img, precision=hi)         # (blk, W)
+    t = jax.lax.dot(t, lx_ref[...].T, precision=hi)         # (blk, out_w)
     out_w = t.shape[1]
     i = pl.program_id(1)
     rows = i * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, out_w), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (blk, out_w), 1)
     inside = ((rows >= top) & (rows < top + ch)
               & (cols >= left) & (cols < left + cw))
-    norm = t * sb_ref[0, 0] + sb_ref[0, 1]
+    n = pl.program_id(0)
+    norm = t * sb_ref[n, 0] + sb_ref[n, 1]
     o_ref[0] = jnp.where(inside, norm,
                          jnp.float32(pad_value)).astype(o_ref.dtype)
 
@@ -115,12 +141,13 @@ def letterbox_normalize(img: jax.Array, ly: jax.Array, lx: jax.Array,
             pl.BlockSpec((1, H, W), lambda n, i: (n, 0, 0)),
             pl.BlockSpec((blk, H), lambda n, i: (i, 0)),
             pl.BlockSpec((out_w, W), lambda n, i: (0, 0)),
-            pl.BlockSpec((1, 2), lambda n, i: (n, 0),
-                         memory_space=pltpu.SMEM),
+            # whole (NB, 2) table in SMEM, row picked by program_id
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, blk, out_w), lambda n, i: (n, i, 0)),
         out_shape=jax.ShapeDtypeStruct((NB, out_h + pad, out_w),
                                        jnp.float32),
+        compiler_params=plane_compiler_params(H, W, blk, out_w),
         interpret=interpret,
     )(img, ly, lx, sb)
     return out[:, :out_h]

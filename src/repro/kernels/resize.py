@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.roofline import hw
+
 
 def _interp_matrix(out_n: int, in_n: int) -> np.ndarray:
     """Rows are bilinear weights (align_corners=False)."""
@@ -36,6 +38,25 @@ def _interp_matrix(out_n: int, in_n: int) -> np.ndarray:
     m[np.arange(out_n), lo] += 1.0 - frac
     m[np.arange(out_n), hi] += frac
     return m
+
+
+def plane_vmem_bytes(H: int, W: int, blk: int, out_w: int) -> int:
+    """VMEM one grid step holds: the whole (H, W) input plane, a (blk, H)
+    row-operator block, the (out_w, W) column operator and a (blk, out_w)
+    output block, double-buffered in float32."""
+    return (H * W + blk * H + out_w * W + blk * out_w) * 4 * 2
+
+
+def plane_compiler_params(H: int, W: int, blk: int,
+                          out_w: int) -> pltpu.CompilerParams | None:
+    """A full-HD plane outgrows Mosaic's default scoped VMEM whatever the
+    row block is, so ask for what the blocks need (with a quarter of
+    headroom for the intermediate) when that is more."""
+    need = plane_vmem_bytes(H, W, blk, out_w)
+    if need <= hw.SCOPED_VMEM_BYTES:
+        return None
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=min(need + need // 4, hw.VMEM_BYTES * 3 // 4))
 
 
 def _kernel(img_ref, ry_ref, rx_ref, o_ref):
@@ -72,6 +93,7 @@ def resize_bilinear(img: jax.Array, out_h: int, out_w: int, *,
         ],
         out_specs=pl.BlockSpec((1, blk, out_w), lambda n, i: (n, i, 0)),
         out_shape=jax.ShapeDtypeStruct((NB, out_h + pad, out_w), img.dtype),
+        compiler_params=plane_compiler_params(H, W, blk, out_w),
         interpret=interpret,
     )(x, ry, rx)
     out = out[:, :out_h]

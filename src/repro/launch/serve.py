@@ -7,14 +7,33 @@ AI-tax report (the paper's measurement, applied to LM serving).
 from __future__ import annotations
 
 import argparse
+import functools
 
 import numpy as np
 
 import jax
+import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.serve.engine import Request, ServingEngine
+
+
+def load(arch: str, *, smoke: bool = False, seed: int = 0):
+    """(model, params) as the serving CLI builds them.
+
+    Published widths keep the config's dtype (bf16: llama3-8b at float32
+    would be 32 GB against a 16 GB chip); smoke presets run in float32.
+    Parameters are random from ``seed``, made in one jitted program so
+    no float32 draw of a leaf is held beside the rest.
+    """
+    cfg = get_config(arch, smoke=smoke)
+    if smoke:
+        cfg = cfg.replace(dtype="float32")
+    model = build_model(cfg)
+    init = jax.jit(functools.partial(model.init, dtype=jnp.dtype(cfg.dtype)))
+    return model, init(jax.random.PRNGKey(seed))
 
 
 def main() -> None:
@@ -28,11 +47,9 @@ def main() -> None:
     ap.add_argument("--cache-len", type=int, default=96)
     args = ap.parse_args()
 
-    cfg = get_config(args.arch, smoke=args.smoke)
-    if args.smoke:
-        cfg = cfg.replace(dtype="float32")
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    enable_compile_cache()
+    model, params = load(args.arch, smoke=args.smoke)
+    cfg = model.cfg
     eng = ServingEngine(model, params, batch_slots=args.slots,
                         cache_len=args.cache_len)
     rng = np.random.default_rng(0)

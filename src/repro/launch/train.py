@@ -16,6 +16,7 @@ import jax
 
 from repro.configs import get_config
 from repro.data.tokens import TokenLoader
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.train.optimizer import AdamWConfig, adamw_update
 from repro.train.trainer import Trainer, TrainerConfig
@@ -34,6 +35,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.smoke:
         cfg = cfg.replace(dtype="float32")

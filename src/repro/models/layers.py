@@ -7,6 +7,7 @@ three can never drift apart.
 """
 from __future__ import annotations
 
+import zlib
 from dataclasses import dataclass, field
 
 import jax
@@ -48,9 +49,10 @@ def init_params(tree, key: jax.Array, dtype=jnp.float32):
             return jnp.zeros(p.shape, dt)
         if p.init == "ones":
             return jnp.ones(p.shape, dt)
-        k = key
-        for entry in path:
-            k = jax.random.fold_in(k, hash(str(entry)) % (2**31))
+        # crc32 of the leaf's path, not hash(): str hashes change with
+        # every process, and weights from one seed must not
+        name = jax.tree_util.keystr(path).encode()
+        k = jax.random.fold_in(key, zlib.crc32(name) % (2**31))
         fan_in = p.shape[-2] if len(p.shape) >= 2 else p.shape[-1]
         scale = p.scale if p.scale is not None else fan_in ** -0.5
         return (jax.random.normal(k, p.shape, jnp.float32) * scale).astype(dt)

@@ -38,7 +38,10 @@ def _use_pallas(impl: ops.Impl | None) -> tuple[bool, bool]:
 def _yuv_to_rgb_xla(yuv):
     x = jnp.moveaxis(yuv, -3, -1).astype(jnp.float32)
     x = x - jnp.asarray([0.0, 128.0, 128.0], jnp.float32)
-    rgb = x @ jnp.asarray(_host._YUV_TO_RGB.T)
+    # HIGHEST: at the TPU's default precision the bf16-rounded
+    # coefficients move pixels by one level against the host baseline
+    rgb = jnp.matmul(x, jnp.asarray(_host._YUV_TO_RGB.T),
+                     precision=jax.lax.Precision.HIGHEST)
     return jnp.clip(jnp.round(rgb), 0.0, 255.0).astype(jnp.uint8)
 
 
@@ -69,7 +72,8 @@ def _letterbox_xla(img, ly, lx, sb_scale, sb_offset):
     # one program: resize (two contractions), affine, pad fill — the
     # mask is implicit in the zero rows of the embedded operators, so
     # pad cells come out as 0 * scale + offset_pad handled below
-    t = jnp.einsum("oh,bhwc,pw->bcop", ly, img.astype(jnp.float32), lx)
+    t = jnp.einsum("oh,bhwc,pw->bcop", ly, img.astype(jnp.float32), lx,
+                   precision=jax.lax.Precision.HIGHEST)
     s = jnp.asarray(sb_scale, jnp.float32)[None, :, None, None]
     o = jnp.asarray(sb_offset, jnp.float32)[None, :, None, None]
     return jnp.moveaxis(t * s + o, 1, -1)

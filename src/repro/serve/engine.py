@@ -48,36 +48,51 @@ import jax.numpy as jnp
 from repro.core.batching import Batcher
 from repro.core.events import EventLog
 from repro.core.metrics import LatencyStats, SLOReport, TailSLO
+from repro.kernels import ops
 
 
 # Jitted step functions live at module level with the (frozen, hashable)
 # Model as a static argument: every engine over the same model shares one
 # compiled executable instead of paying a per-instance retrace — the
 # decode-batching benchmark times steady-state dispatch, not compilation.
-@functools.partial(jax.jit, static_argnums=0)
-def _step_fused(model, params, cache, tokens):
-    logits, cache = model.decode_step(params, cache, tokens)
+# The kernel implementation is static too: the models read ops' default
+# while tracing, so the engine passes the default in force at call time
+# and a program traced under one implementation is never reused under
+# another.
+@functools.partial(jax.jit, static_argnums=(0, 1, 4))
+def _prefill(model, impl, params, tokens, cache_len):
+    with ops.default_impl(impl):
+        return model.prefill(params, {"tokens": tokens}, cache_len=cache_len)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _step_fused(model, impl, params, cache, tokens):
+    with ops.default_impl(impl):
+        logits, cache = model.decode_step(params, cache, tokens)
     return jnp.argmax(logits.reshape(-1)).astype(jnp.int32), cache
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _step_plain(model, params, cache, tokens):
-    return model.decode_step(params, cache, tokens)
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _step_plain(model, impl, params, cache, tokens):
+    with ops.default_impl(impl):
+        return model.decode_step(params, cache, tokens)
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _step_batched_fused(model, params, blocks, packed):
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _step_batched_fused(model, impl, params, blocks, packed):
     # packed (2, B) int32: row 0 the feedback tokens, row 1 per-slot
     # kv_len — one h2d upload per tick instead of two
-    logits, blocks = model.decode_step_ragged(params, blocks,
-                                              packed[0][:, None], packed[1])
+    with ops.default_impl(impl):
+        logits, blocks = model.decode_step_ragged(
+            params, blocks, packed[0][:, None], packed[1])
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), blocks
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _step_batched_plain(model, params, blocks, packed):
-    return model.decode_step_ragged(params, blocks, packed[0][:, None],
-                                    packed[1])
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _step_batched_plain(model, impl, params, blocks, packed):
+    with ops.default_impl(impl):
+        return model.decode_step_ragged(params, blocks, packed[0][:, None],
+                                        packed[1])
 
 
 @functools.partial(jax.jit, static_argnums=0)
@@ -214,8 +229,8 @@ class ServingEngine:
         t0 = time.perf_counter()
         tokens = jnp.asarray(req.prompt[None, :])
         self.log.log_transfer(req.rid, "h2d", int(tokens.nbytes), "prefill")
-        logits, cache = self.model.prefill(self.params, {"tokens": tokens},
-                                           cache_len=self.cache_len)
+        logits, cache = _prefill(self.model, ops.get_default_impl(),
+                                 self.params, tokens, self.cache_len)
         jax.block_until_ready(logits)
         self.log.log(req.rid, "prefill", t0, time.perf_counter(),
                      int(req.prompt.nbytes))
@@ -302,7 +317,8 @@ class ServingEngine:
                 packed = jnp.asarray(
                     np.stack([self._last_tok, self._kv_len]))
                 out, self._blocks = self._decode_batch(
-                    self.params, self._blocks, packed)
+                    ops.get_default_impl(), self.params, self._blocks,
+                    packed)
                 jax.block_until_ready(out)
                 t1 = time.perf_counter()
                 out_host = np.asarray(out)       # the ONE d2h per tick
@@ -358,8 +374,8 @@ class ServingEngine:
                 self.log.log_transfer(req.rid, "h2d", int(tok.nbytes),
                                       "decode")
                 if self.fast_path:
-                    nxt_dev, caches[i] = self._decode(self.params, caches[i],
-                                                      tok)
+                    nxt_dev, caches[i] = self._decode(
+                        ops.get_default_impl(), self.params, caches[i], tok)
                     jax.block_until_ready(nxt_dev)
                     self.log.log(req.rid, "decode", t0, time.perf_counter())
                     self.d2h_syncs += 1
@@ -368,8 +384,8 @@ class ServingEngine:
                                           int(nxt_dev.nbytes), "decode")
                     nxt = int(nxt_dev)
                 else:
-                    logits, caches[i] = self._decode(self.params, caches[i],
-                                                     tok)
+                    logits, caches[i] = self._decode(
+                        ops.get_default_impl(), self.params, caches[i], tok)
                     jax.block_until_ready(logits)
                     self.log.log(req.rid, "decode", t0, time.perf_counter())
                     row = np.asarray(logits[0])

@@ -129,3 +129,23 @@ def test_tuned_matmul_matches_ref(cache, monkeypatch):
     np.testing.assert_allclose(out, ref.matmul(a, b), atol=1e-4, rtol=1e-4)
     key = autotune.matmul_key(13, 200, 37, "float32")
     assert cache.lookup(key) is not None
+
+
+def test_no_overlay_unless_asked(tmp_path, monkeypatch):
+    """A run from a checkout reads committed tilings and writes nothing:
+    without an explicit path or $REPRO_AUTOTUNE_CACHE there is no
+    overlay, and a miss is memoized in memory only."""
+    monkeypatch.delenv("REPRO_AUTOTUNE_CACHE", raising=False)
+    monkeypatch.setenv("HOME", str(tmp_path))
+    c = autotune.AutotuneCache(seed_path=None)
+    assert c.path is None
+    t = autotune.matmul_tiling(64, 200, 48, cache=c)
+    assert c.lookup(autotune.matmul_key(64, 200, 48, "float32"))["blocks"] == t
+    assert not any(tmp_path.rglob("*"))
+    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "o.json"))
+    assert autotune.AutotuneCache().path == tmp_path / "o.json"
+
+
+def test_measured_mode_needs_a_tpu(cache):
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        autotune.matmul_tiling(8, 128, 128, cache=cache, mode="measured")

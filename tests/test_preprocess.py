@@ -143,6 +143,43 @@ def test_letterbox_host_device_parity():
     np.testing.assert_allclose(got_h, got_d, atol=1e-4)
 
 
+# ---- Pallas preprocess kernels (interpret mode) vs the baselines ----------
+
+@pytest.mark.parametrize("H", [20, 70])   # 70: two 64-row bands, one short
+def test_yuv_kernel_matches_host(H):
+    import jax.numpy as jnp
+    yuv = np.random.default_rng(5).integers(0, 256, (2, 3, H, 24), np.uint8)
+    got = np.asarray(pre_device.yuv_to_rgb(jnp.asarray(yuv),
+                                           impl="pallas_interpret"))
+    ref = pre_host.yuv_to_rgb(yuv)
+    # f32 elementwise vs the host's f32 matmul: a .5 tie may round apart
+    assert got.shape == ref.shape and got.dtype == np.uint8
+    assert np.abs(got.astype(int) - ref.astype(int)).max() <= 1
+
+
+@pytest.mark.parametrize("dtype", [np.uint8, np.float32])
+def test_letterbox_kernel_matches_host(dtype):
+    """Per-channel scale/offset rows come from the SMEM table by plane
+    index, so two frames x three channels must each get their own."""
+    import jax.numpy as jnp
+    img = np.random.default_rng(6).uniform(0, 255, (2, 20, 34, 3))
+    img = img.astype(dtype)
+    kw = dict(scale=np.float32([1 / 255, 2 / 255, 3 / 255]),
+              offset=np.float32([-0.5, 0.0, 0.25]), pad_value=0.125)
+    got = np.asarray(pre_device.letterbox_normalize(
+        jnp.asarray(img), 28, 28, impl="pallas_interpret", **kw))
+    ref = pre_host.letterbox_normalize(img.astype(np.float32), 28, 28, **kw)
+    np.testing.assert_allclose(got, ref, atol=1e-4)
+
+
+def test_iou_kernel_matches_xla():
+    import jax.numpy as jnp
+    boxes, _ = _random_boxes(np.random.default_rng(7), 37)
+    got = pre_device.iou_matrix(jnp.asarray(boxes), impl="pallas_interpret")
+    ref = pre_device.iou_matrix(jnp.asarray(boxes), impl="xla")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-6)
+
+
 # ---- five-way attribution --------------------------------------------------
 
 def test_event_log_five_way_sums_to_one():

@@ -216,3 +216,18 @@ def test_engine_ttft_samples_cover_all_requests():
                                            for i in range(4)])
     ttfts = eng.ttft_samples()
     assert len(ttfts) == 4 and all(t > 0 for t in ttfts)
+
+
+def test_engine_traces_once_per_kernel_impl():
+    """The models read ops' default impl while tracing: a step compiled
+    under one implementation must not be reused under another."""
+    from repro.kernels import ops
+    from repro.serve import engine as E
+    eng, cfg = _engine(slots=1, cache_len=40)
+    prompt = np.random.default_rng(37).integers(0, cfg.vocab_size, 8)
+    before = E._step_batched_fused._cache_size()
+    for impl in ("xla", "pallas_interpret"):
+        with ops.default_impl(impl):
+            eng.submit(Request(len(impl), prompt, max_tokens=2))
+            eng.run()
+    assert E._step_batched_fused._cache_size() == before + 2
