@@ -10,9 +10,11 @@ from __future__ import annotations
 
 import json
 import math
+import threading
 import time
 from collections import defaultdict
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 
 FIVE_WAY = ("pre", "ai", "post", "transfer", "queue")
@@ -123,16 +125,86 @@ class Event:
         return self.t_end - self.t_start
 
 
-class EventLog:
-    """Append-only event store + aggregations."""
+class _BatchSpan(NamedTuple):
+    """One batched span, stored once and expanded per request on read."""
+    rids: tuple
+    stage: str
+    t_start: float
+    t_end: float
+    payload_bytes: int
+    split_payload: bool
+    meta: dict
 
-    def __init__(self):
-        self.events: list[Event] = []
+    def expand(self):
+        B = max(len(self.rids), 1)
+        dt = (self.t_end - self.t_start) / B
+        pay = self.payload_bytes
+        for i, rid in enumerate(self.rids):
+            per = (pay // B + (pay % B if i == 0 else 0)
+                   if self.split_payload else pay)
+            yield Event(rid, self.stage, self.t_start + i * dt,
+                        self.t_start + (i + 1) * dt, per,
+                        {"batch_size": B, **self.meta})
+
+
+class _BatchTransfers(NamedTuple):
+    """One batched boundary crossing (both directions), stored once."""
+    rids: tuple
+    boundary: str
+    h2d: int
+    d2h: int
+    t: float
+
+    def expand(self):
+        B = max(len(self.rids), 1)
+        for j, rid in enumerate(self.rids):
+            extra_up, extra_dn = ((self.h2d % B, self.d2h % B) if j == 0
+                                  else (0, 0))
+            for direction, n in (("h2d", self.h2d // B + extra_up),
+                                 ("d2h", self.d2h // B + extra_dn)):
+                yield Event(rid, "transfer", self.t, self.t, n,
+                            {"kind": "transfer", "direction": direction,
+                             "boundary": self.boundary})
+
+
+class EventLog:
+    """Append-only event store + aggregations.
+
+    A batched call stores one record; :attr:`events` expands the records
+    into per-request :class:`Event` objects, in logging order, on read. With
+    an ``owner`` (e.g. ``"engine"``), each :class:`Timer` over this log
+    also opens a profiler annotation ``<owner>.<stage>``; a log without
+    one emits none.
+    """
+
+    def __init__(self, owner: str | None = None):
+        self.owner = owner
+        self._records: list = []
+        self._flat: list[Event] = []      # records[:_n_flat], expanded
+        self._n_flat = 0
+        self._expand_lock = threading.Lock()
+
+    @property
+    def events(self) -> tuple[Event, ...]:
+        """Per-request events, batched records expanded (read-only)."""
+        return tuple(self._expanded())
+
+    def _expanded(self) -> list[Event]:
+        n = len(self._records)
+        if self._n_flat < n:
+            with self._expand_lock:
+                for rec in self._records[self._n_flat:n]:
+                    if isinstance(rec, Event):
+                        self._flat.append(rec)
+                    else:
+                        self._flat.extend(rec.expand())
+                self._n_flat = max(self._n_flat, n)
+        return self._flat
 
     def log(self, request_id: int, stage: str, t_start: float, t_end: float,
             payload_bytes: int = 0, **meta) -> Event:
         ev = Event(request_id, stage, t_start, t_end, payload_bytes, meta)
-        self.events.append(ev)
+        self._records.append(ev)
         return ev
 
     def log_transfer(self, request_id: int, direction: str, nbytes: int,
@@ -147,8 +219,9 @@ class EventLog:
         that happen inside a jitted program aren't separately timeable
         — callers may log them as zero-duration point events; the bytes
         are the quantity of record (`transfer_bytes()`), while timed
-        crossings (e.g. TaxedStep's explicit device_put/get) carry real
-        spans and show up in the time split too.
+        crossings (e.g. TaxedStep's explicit device_put/get, or a
+        :class:`Timer` with stage ``"transfer"``) carry real spans and
+        show up in the time split too.
         """
         t0 = time.perf_counter() if t_start is None else t_start
         return self.log(request_id, stage, t0, t0 if t_end is None else t_end,
@@ -160,22 +233,17 @@ class EventLog:
                        **meta) -> None:
         """Amortize one batched span into per-request events.
 
-        The batch's wall span is partitioned into ``len(rids)`` equal
-        slices (``duration = span / B``), each tagged
-        ``batch_size=B`` — the discipline docs/ai_tax_accounting.md
-        describes, shared by the pipeline's AI stages, the preprocess
-        stage, and the benchmarks. ``payload_bytes`` is per-item by
-        default; with ``split_payload`` it is a batch total, divided
-        across items with the remainder on the first so the batch sum
-        stays exact.
+        Stored as one record; :attr:`events` reads it as ``len(rids)``
+        events over equal slices of the batch's wall span
+        (``duration = span / B``), each tagged ``batch_size=B`` — the
+        discipline docs/ai_tax_accounting.md describes, shared by the
+        pipeline's AI stages, the preprocess stage, the serving engine
+        and the benchmarks. ``payload_bytes`` is per-item by default;
+        with ``split_payload`` it is a batch total, divided across items
+        with the remainder on the first so the batch sum stays exact.
         """
-        B = max(len(rids), 1)
-        dt = (t_end - t_start) / B
-        for i, rid in enumerate(rids):
-            per = (payload_bytes // B + (payload_bytes % B if i == 0 else 0)
-                   if split_payload else payload_bytes)
-            self.log(rid, stage, t_start + i * dt, t_start + (i + 1) * dt,
-                     payload_bytes=per, batch_size=B, **meta)
+        self._records.append(_BatchSpan(tuple(rids), stage, t_start, t_end,
+                                        payload_bytes, split_payload, meta))
 
     def log_batch_transfers(self, rids, boundary: str, h2d: int, d2h: int,
                             t: float | None = None) -> None:
@@ -183,21 +251,19 @@ class EventLog:
 
         The batch's boundary bytes (padding included — padded rows
         cross too) are split across its items, remainder on the first,
-        so per-request accounting and batch totals both stay exact.
+        so per-request accounting and batch totals both stay exact: one
+        record, read as a zero-length h2d and d2h event per item.
         Shared by the streaming pipeline's AI stages and the
         preprocess stage's device placement.
         """
         t = time.perf_counter() if t is None else t
-        B = max(len(rids), 1)
-        for j, rid in enumerate(rids):
-            extra_up, extra_dn = (h2d % B, d2h % B) if j == 0 else (0, 0)
-            self.log_transfer(rid, "h2d", h2d // B + extra_up, boundary, t)
-            self.log_transfer(rid, "d2h", d2h // B + extra_dn, boundary, t)
+        self._records.append(_BatchTransfers(tuple(rids), boundary, h2d, d2h,
+                                             t))
 
     def transfer_bytes(self, boundary: str | None = None) -> dict[str, int]:
         """Total transferred bytes by direction (optionally one boundary)."""
         out = {"h2d": 0, "d2h": 0}
-        for ev in self.events:
+        for ev in self._expanded():
             if ev.meta.get("kind") != "transfer":
                 continue
             if boundary is not None and ev.meta.get("boundary") != boundary:
@@ -211,7 +277,7 @@ class EventLog:
 
     def stage_latencies(self) -> dict[str, list[float]]:
         out: dict[str, list[float]] = defaultdict(list)
-        for ev in self.events:
+        for ev in self._expanded():
             out[ev.stage].append(ev.duration)
         return dict(out)
 
@@ -230,7 +296,7 @@ class EventLog:
     def end_to_end(self, stages: list[str] | None = None) -> list[float]:
         """Per-request total latency (first start -> last end)."""
         spans: dict[int, list[Event]] = defaultdict(list)
-        for ev in self.events:
+        for ev in self._expanded():
             if stages is None or ev.stage in stages:
                 spans[ev.request_id].append(ev)
         return [max(e.t_end for e in evs) - min(e.t_start for e in evs)
@@ -259,7 +325,7 @@ class EventLog:
         """Wrap a stage->bucket map with the authoritative-kind rule:
         stages whose events carry ``kind="transfer"`` meta are forced
         into the ``transfer`` bucket regardless of name."""
-        transfer_set = {ev.stage for ev in self.events
+        transfer_set = {ev.stage for ev in self._expanded()
                         if ev.meta.get("kind") == "transfer"}
         return lambda s: "transfer" if s in transfer_set else category_of(s)
 
@@ -279,7 +345,7 @@ class EventLog:
         """
         cat = self._kind_aware(category_of)
         out = dict.fromkeys(FIVE_WAY, 0.0)
-        for ev in self.events:
+        for ev in self._expanded():
             out[cat(ev.stage)] += ev.duration
         return out
 
@@ -299,7 +365,7 @@ class EventLog:
             raise ValueError("window_s must be positive")
         cat = self._kind_aware(category_of)
         acc: dict[int, dict] = {}
-        for ev in self.events:
+        for ev in self._expanded():
             d = acc.setdefault(int(ev.t_end // window_s),
                                dict.fromkeys(FIVE_WAY, 0.0))
             d[cat(ev.stage)] += ev.duration
@@ -329,7 +395,7 @@ class EventLog:
         offload benchmarks sweep.
         """
         by_stage = self.breakdown()
-        transfer_set = {ev.stage for ev in self.events
+        transfer_set = {ev.stage for ev in self._expanded()
                         if ev.meta.get("kind") == "transfer"}
         ai = sum(v for s, v in by_stage.items() if s in ai_stages)
         transfer = sum(v for s, v in by_stage.items() if s in transfer_set)
@@ -349,16 +415,17 @@ class EventLog:
 
     def throughput(self) -> float:
         """Completed requests per second over the observed span."""
-        if not self.events:
+        evs = self._expanded()
+        if not evs:
             return 0.0
-        t0 = min(e.t_start for e in self.events)
-        t1 = max(e.t_end for e in self.events)
-        n = len({e.request_id for e in self.events})
+        t0 = min(e.t_start for e in evs)
+        t1 = max(e.t_end for e in evs)
+        n = len({e.request_id for e in evs})
         return n / (t1 - t0) if t1 > t0 else 0.0
 
     def to_jsonl(self, path: str) -> None:
         with open(path, "w") as f:
-            for ev in self.events:
+            for ev in self._expanded():
                 f.write(json.dumps({
                     "request_id": ev.request_id, "stage": ev.stage,
                     "t_start": ev.t_start, "t_end": ev.t_end,
@@ -366,18 +433,50 @@ class EventLog:
 
 
 class Timer:
-    """Context manager that logs an event on exit (live pipelines)."""
+    """Context manager over one span: the profiler and the EventLog.
 
-    def __init__(self, log: EventLog, request_id: int, stage: str,
+    ``request_id`` is one id, or a list of ids for a batch. While the span
+    is open, a log with an ``owner`` holds a profiler annotation named
+    ``<owner>.<stage>`` (``<owner>.<direction>.<boundary>`` for a
+    ``transfer``), with the ids and ``meta`` as its keyword metadata and
+    never in its name. On exit the span is logged: one event for one id;
+    for a list, one batch record (:meth:`EventLog.log_batch_span`) whose
+    ``payload_bytes`` is the batch's total, split across the ids. A
+    ``transfer`` span is logged as a timed crossing (``kind="transfer"``)
+    and needs ``direction`` and ``boundary`` in ``meta``. The body may set
+    ``payload_bytes`` and add to ``meta`` before the span closes.
+    """
+
+    def __init__(self, log: EventLog, request_id, stage: str,
                  payload_bytes: int = 0, clock=time.perf_counter, **meta):
+        if stage == "transfer":
+            meta = {"kind": "transfer", **meta}
         self.log, self.request_id, self.stage = log, request_id, stage
         self.payload_bytes, self.meta, self.clock = payload_bytes, meta, clock
+        self._span = None
 
     def __enter__(self):
+        owner = self.log.owner
+        if owner is not None:
+            from jax.profiler import TraceAnnotation
+            name = (f"{owner}.{self.meta['direction']}.{self.meta['boundary']}"
+                    if self.stage == "transfer" else f"{owner}.{self.stage}")
+            ids = self.request_id
+            key = "rids" if isinstance(ids, (list, tuple)) else "rid"
+            self._span = TraceAnnotation(name, **{key: ids}, **self.meta)
+            self._span.__enter__()
         self.t0 = self.clock()
         return self
 
     def __exit__(self, *exc):
-        self.log.log(self.request_id, self.stage, self.t0, self.clock(),
-                     self.payload_bytes, **self.meta)
+        t1 = self.clock()
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        if isinstance(self.request_id, (list, tuple)):
+            self.log.log_batch_span(self.request_id, self.stage, self.t0, t1,
+                                    self.payload_bytes, split_payload=True,
+                                    **self.meta)
+        else:
+            self.log.log(self.request_id, self.stage, self.t0, t1,
+                         self.payload_bytes, **self.meta)
         return False

@@ -172,15 +172,18 @@ def attn_decode(cfg, spec, p, x, cache, cur_len):
     q, k, v = _project_qkv(cfg, p, x, pos)
     L = cache["k"].shape[1]
     slot = cur_len % L if spec.window else cur_len
-    if ragged:
-        # per-row insert: row b writes its token at its own slot[b]
-        ck = cache["k"].at[jnp.arange(B), slot].set(k[:, 0])
-        cv = cache["v"].at[jnp.arange(B), slot].set(v[:, 0])
-    else:
-        ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, slot, axis=1)
-    ck = shard(ck, "batch", "kv_seq", "kv_heads", None)
-    cv = shard(cv, "batch", "kv_seq", "kv_heads", None)
+    with jax.named_scope("kv_update"):
+        if ragged:
+            # per-row insert: row b writes its token at its own slot[b]
+            ck = cache["k"].at[jnp.arange(B), slot].set(k[:, 0])
+            cv = cache["v"].at[jnp.arange(B), slot].set(v[:, 0])
+        else:
+            ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot,
+                                                     axis=1)
+            cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, slot,
+                                                     axis=1)
+        ck = shard(ck, "batch", "kv_seq", "kv_heads", None)
+        cv = shard(cv, "batch", "kv_seq", "kv_heads", None)
     if spec.window:
         # rolling cache: slot s holds position s + L*floor((t-s)/L), t = cur_len
         s_idx = jnp.arange(L)

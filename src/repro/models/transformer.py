@@ -126,24 +126,30 @@ def _apply_layer_prefill(cfg, spec, lp, x, positions, cache_len, aux):
 
 
 def _apply_layer_decode(cfg, spec, lp, x, cache, cur_len):
+    # named scopes (``attn``/``mamba``/``rwkv``, ``moe``/``mlp``) tag the
+    # ops' metadata only, so a trace viewer can tell which part of the
+    # step owns an op
     h = apply_norm(cfg, lp["ln1"], x)
-    if spec.kind == "attn":
-        mix, cache = attn.attn_decode(cfg, spec, lp["mix"], h, cache, cur_len)
-    elif spec.kind == "mamba":
-        mix, cache = ssm.mamba_decode(cfg, lp["mix"], h, cache)
-    else:
-        mix, new = ssm.rwkv_decode(cfg, lp["mix"], h, {k: cache[k] for k in
-                                                       ("x_tm", "h")})
-        cache = {**cache, **new}
+    with jax.named_scope(spec.kind):
+        if spec.kind == "attn":
+            mix, cache = attn.attn_decode(cfg, spec, lp["mix"], h, cache,
+                                          cur_len)
+        elif spec.kind == "mamba":
+            mix, cache = ssm.mamba_decode(cfg, lp["mix"], h, cache)
+        else:
+            mix, new = ssm.rwkv_decode(cfg, lp["mix"], h,
+                                       {k: cache[k] for k in ("x_tm", "h")})
+            cache = {**cache, **new}
     x = x + mix
     h = apply_norm(cfg, lp["ln2"], x)
-    if spec.moe:
-        out, _ = moe_mod.moe_apply(cfg, lp["mlp"], h)
-    elif cfg.mlp_kind == "rwkv":
-        out = ssm.rwkv_cm_decode(cfg, lp["mlp"], h, cache["x_cm"])
-        cache = {**cache, "x_cm": h[:, 0]}
-    else:
-        out = mlp_apply(cfg, lp["mlp"], h)
+    with jax.named_scope("moe" if spec.moe else "mlp"):
+        if spec.moe:
+            out, _ = moe_mod.moe_apply(cfg, lp["mlp"], h)
+        elif cfg.mlp_kind == "rwkv":
+            out = ssm.rwkv_cm_decode(cfg, lp["mlp"], h, cache["x_cm"])
+            cache = {**cache, "x_cm": h[:, 0]}
+        else:
+            out = mlp_apply(cfg, lp["mlp"], h)
     x = x + out
     return x, cache
 
@@ -261,8 +267,9 @@ def _lm_decode_blocks(cfg, params, blocks, tokens, cur_len):
         return x, new
 
     x, new_caches = jax.lax.scan(block_fn, x, (params["blocks"], blocks))
-    x = apply_norm(cfg, params["ln_f"], x)
-    logits = unembed(cfg, params["embed"], x[:, -1:])[:, 0]
+    with jax.named_scope("lm_head"):
+        x = apply_norm(cfg, params["ln_f"], x)
+        logits = unembed(cfg, params["embed"], x[:, -1:])[:, 0]
     return logits, new_caches
 
 

@@ -16,11 +16,15 @@ Production concerns covered at container scale:
     per-token host round-trips the AI-tax paper predicts dominate once
     the AI core is fast (``benchmarks/fig_decode_batching.py`` measures
     the gap);
-  * per-request AI-tax events (queue wait, prefill, decode — batched
-    decode spans amortized per slot) via the same EventLog as the
-    paper's pipeline, with every device->host fetch both counted
-    (``d2h_syncs``/``d2h_bytes``) and logged as transfer events so the
-    ledger accounts every boundary byte;
+  * per-request AI-tax events via the same EventLog as the paper's
+    pipeline: queue wait, and in each continuous tick (a profiler step
+    ``engine.tick``) one span per host phase — ``pre_admit``, per admit
+    ``prefill``, its token read and ``pre_insert``, then the upload,
+    ``decode``, the readback and ``post_tokens`` — each stored once over
+    the busy slots (amortized per slot on read) and annotated for the
+    profiler as ``engine.<phase>``. Every device->host fetch is both
+    counted (``d2h_syncs``/``d2h_bytes``) and logged as a transfer so
+    the ledger accounts every boundary byte;
   * straggler mitigation hook: slots exceeding ``max_tokens`` are
     evicted, where ``max_tokens`` bounds the total generated tokens
     (prefill's token included — ``max_tokens=1`` emits exactly one
@@ -35,6 +39,7 @@ lowers for the production mesh.
 from __future__ import annotations
 
 import functools
+import gc
 import queue
 import threading
 import time
@@ -46,8 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.batching import Batcher
-from repro.core.events import EventLog
-from repro.core.metrics import LatencyStats, SLOReport, TailSLO
+from repro.core.events import EventLog, Timer
 from repro.kernels import ops
 
 
@@ -100,6 +104,21 @@ def _insert_slot(model, blocks, one_blocks, slot):
     return model.insert_prefill(blocks, one_blocks, slot)
 
 
+_GC_OPEN: list = []
+
+
+def _gc_span(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: a profiler span ``python.gc`` around each
+    collection, so a device gap a collection causes is charged to it and
+    not to the engine phase that was open."""
+    if phase == "start":
+        span = jax.profiler.TraceAnnotation("python.gc")
+        span.__enter__()
+        _GC_OPEN.append(span)
+    elif _GC_OPEN:
+        _GC_OPEN.pop().__exit__(None, None, None)
+
+
 @dataclass
 class Request:
     rid: int
@@ -120,7 +139,9 @@ class ServingEngine:
         self.params = params
         self.slots = batch_slots
         self.cache_len = cache_len
-        self.log = EventLog()
+        self.log = EventLog(owner="engine")
+        if _gc_span not in gc.callbacks:
+            gc.callbacks.append(_gc_span)
         if scheduler not in ("continuous", "slot"):
             raise ValueError(f"scheduler must be continuous/slot: {scheduler!r}")
         if model.cfg.encdec and scheduler == "continuous":
@@ -139,7 +160,7 @@ class ServingEngine:
         self.degrade_timeline: list[tuple[float, int, str]] = []
         # admission bound: submissions beyond max_queue pending requests
         # are rejected at the door (logged as zero-span "reject" events,
-        # so ai_tax()/latency_report() see the shed load); None = accept
+        # so tax_report() sees the shed load); None = accept
         # everything and let queue wait absorb the pressure
         self.max_queue = max_queue
         self.rejected = 0
@@ -165,6 +186,7 @@ class ServingEngine:
         self._kv_len = np.zeros(batch_slots, np.int32)
         self._last_tok = np.zeros(batch_slots, np.int32)
         self._blocks = None          # batched (slots, cache_len, ...) cache
+        self._tick_no = 0            # continuous ticks run, for step_num
         # fast_path: greedy token selection is fused into the jitted
         # decode program, so one int32 per slot crosses device->host per
         # step; the unfused path fetches the full logit rows and
@@ -225,28 +247,28 @@ class ServingEngine:
                          accuracy_proxy=lvl.accuracy_proxy, level=lvl.name)
 
     # -- single-sequence prefill per admit ----------------------------------
-    def _prefill_one(self, req: Request):
-        t0 = time.perf_counter()
-        tokens = jnp.asarray(req.prompt[None, :])
-        self.log.log_transfer(req.rid, "h2d", int(tokens.nbytes), "prefill")
-        logits, cache = _prefill(self.model, ops.get_default_impl(),
-                                 self.params, tokens, self.cache_len)
-        jax.block_until_ready(logits)
-        self.log.log(req.rid, "prefill", t0, time.perf_counter(),
-                     int(req.prompt.nbytes))
-        if self.fast_path:
-            # argmax on device; only the winning index crosses
-            idx = jnp.argmax(logits[0]).astype(jnp.int32)
-            nxt = int(idx)
-            self.d2h_syncs += 1
-            self.d2h_bytes += int(idx.nbytes)
-            self.log.log_transfer(req.rid, "d2h", int(idx.nbytes), "prefill")
-        else:
-            row = np.asarray(logits[0])
-            self.d2h_syncs += 1
-            self.d2h_bytes += int(row.nbytes)
-            self.log.log_transfer(req.rid, "d2h", int(row.nbytes), "prefill")
-            nxt = int(np.argmax(row))
+    def _prefill_one(self, req: Request, **tag):
+        with Timer(self.log, req.rid, "prefill", int(req.prompt.nbytes),
+                   **tag):
+            tokens = jnp.asarray(req.prompt[None, :])
+            self.log.log_transfer(req.rid, "h2d", int(tokens.nbytes),
+                                  "prefill")
+            logits, cache = _prefill(self.model, ops.get_default_impl(),
+                                     self.params, tokens, self.cache_len)
+            jax.block_until_ready(logits)
+        with Timer(self.log, req.rid, "transfer", direction="d2h",
+                   boundary="prefill", **tag) as read:
+            if self.fast_path:
+                # argmax on device; only the winning index crosses
+                idx = jnp.argmax(logits[0]).astype(jnp.int32)
+                nxt = int(idx)
+                read.payload_bytes = int(idx.nbytes)
+            else:
+                row = np.asarray(logits[0])
+                nxt = int(np.argmax(row))
+                read.payload_bytes = int(row.nbytes)
+        self.d2h_syncs += 1
+        self.d2h_bytes += read.payload_bytes
         req.tokens.append(nxt)
         req.t_first = time.perf_counter()
         return cache, nxt
@@ -271,21 +293,29 @@ class ServingEngine:
             return self._run_continuous(max_steps)
         return self._run_slot(max_steps)
 
-    def _admit_free_slots(self, finished: list) -> list[int]:
-        """Drain the submission topic into free slots; returns the slots
-        admitted this tick (prefill done, first token emitted)."""
+    def _poll_free_slots(self) -> list[tuple[int, Request]]:
+        """Drain the submission topic into free slots: each request's
+        queue wait is logged and its ``max_tokens`` clamped."""
         free = [i for i in range(self.slots) if self.active[i] is None]
-        admitted = []
         if not free:
-            return admitted
-        for i, req in zip(free, self.admission.poll(len(free))):
+            return []
+        polled = list(zip(free, self.admission.poll(len(free))))
+        for _, req in polled:
             self.log.log(req.rid, "wait", req.t_submit, time.perf_counter())
             self._degrade_clamp(req)
-            cache, _ = self._prefill_one(req)
-            if self._finished_early(req, finished):
-                continue
-            self.active[i] = req
-            admitted.append((i, cache))
+        return polled
+
+    def _prefill_polled(self, polled, finished: list, **tag) -> list:
+        """Prefill each polled request; returns ``(slot, request, cache)``
+        for those that still decode. Every prefill of a tick runs before
+        any of its slot inserts: at cell sizes the device's memory is
+        nearly full, and this order of its large allocations is the one
+        the engine is measured with."""
+        admitted = []
+        for i, req in polled:
+            cache, _ = self._prefill_one(req, **tag)
+            if not self._finished_early(req, finished):
+                admitted.append((i, req, cache))
         return admitted
 
     def _run_continuous(self, max_steps: int) -> list[Request]:
@@ -295,9 +325,23 @@ class ServingEngine:
         steps = 0
         while (any(self.active) or not self._pending.empty()) \
                 and steps < max_steps:
+            n = self._tick_no
+            self._tick_no += 1
+            with jax.profiler.StepTraceAnnotation("engine.tick", step_num=n):
+                self._tick(n, finished)
+            steps += 1
+        return finished
+
+    def _tick(self, n: int, finished: list) -> None:
+        """One continuous tick, one span per host phase: each span is
+        logged over the requests it serves, tagged ``tick=n``."""
+        rids = [r.rid for r in self.active if r is not None]
+        with Timer(self.log, rids, "pre_admit", tick=n):
             self._degrade_tick()
-            for i, cache in self._admit_free_slots(finished):
-                req = self.active[i]
+            polled = self._poll_free_slots()
+            rids += [req.rid for _, req in polled]
+        for i, req, cache in self._prefill_polled(polled, finished, tick=n):
+            with Timer(self.log, req.rid, "pre_insert", tick=n):
                 if self._blocks is None:
                     self._blocks = self.model.init_cache(
                         self.slots, self.cache_len)["blocks"]
@@ -307,48 +351,47 @@ class ServingEngine:
                 # device-side row insert: resident slots' rows untouched
                 self._blocks = self._insert(self._blocks, cache["blocks"],
                                             slot)
-                self._kv_len[i] = len(req.prompt)
-                self._last_tok[i] = req.tokens[-1]
-            idx = [i for i in range(self.slots)
-                   if self.active[i] is not None]
-            if idx:
-                rids = [self.active[i].rid for i in idx]
-                t0 = time.perf_counter()
-                packed = jnp.asarray(
-                    np.stack([self._last_tok, self._kv_len]))
-                out, self._blocks = self._decode_batch(
-                    ops.get_default_impl(), self.params, self._blocks,
-                    packed)
-                jax.block_until_ready(out)
-                t1 = time.perf_counter()
-                out_host = np.asarray(out)       # the ONE d2h per tick
-                self.d2h_syncs += 1
-                self.d2h_bytes += int(out_host.nbytes)
-                self.log.log_batch_span(rids, "decode", t0, t1)
-                # boundary bytes, padding (idle lanes) included: the
-                # whole slot vector crosses in one batched transfer
-                self.log.log_batch_transfers(
-                    rids, "decode", h2d=int(packed.nbytes),
-                    d2h=int(out_host.nbytes), t=t0)
-                nxt = out_host if self.fast_path else out_host.argmax(-1)
-                for i in idx:
-                    req = self.active[i]
-                    tok_i = int(nxt[i])
-                    req.tokens.append(tok_i)
-                    self._last_tok[i] = tok_i
-                    self._kv_len[i] += 1
-                    if (len(req.tokens) >= req.max_tokens
-                            or self._kv_len[i] >= self.cache_len - 1):
-                        # leave at a token boundary: the slot's rows stay
-                        # in the cache, masked out by kv_len=0 until a
-                        # new admission overwrites them
-                        req.done = True
-                        finished.append(req)
-                        self.active[i] = None
-                        self._kv_len[i] = 0
-                        self._last_tok[i] = 0
-            steps += 1
-        return finished
+            self.active[i] = req
+            self._kv_len[i] = len(req.prompt)
+            self._last_tok[i] = req.tokens[-1]
+        idx = [i for i in range(self.slots) if self.active[i] is not None]
+        if not idx:
+            return
+        rids = [self.active[i].rid for i in idx]
+        # boundary bytes, padding (idle lanes) included: the whole slot
+        # vector crosses in one batched transfer each way
+        with Timer(self.log, rids, "transfer", direction="h2d",
+                   boundary="decode", tick=n) as up:
+            packed = jnp.asarray(np.stack([self._last_tok, self._kv_len]))
+            up.payload_bytes = int(packed.nbytes)
+        with Timer(self.log, rids, "decode", tick=n, slots_busy=len(idx),
+                   queue_depth=self.queue_depth):
+            out, self._blocks = self._decode_batch(
+                ops.get_default_impl(), self.params, self._blocks, packed)
+            jax.block_until_ready(out)
+        with Timer(self.log, rids, "transfer", int(out.nbytes),
+                   direction="d2h", boundary="decode", tick=n):
+            out_host = np.asarray(out)       # the ONE d2h per tick
+        self.d2h_syncs += 1
+        self.d2h_bytes += int(out_host.nbytes)
+        with Timer(self.log, rids, "post_tokens", tick=n):
+            nxt = out_host if self.fast_path else out_host.argmax(-1)
+            for i in idx:
+                req = self.active[i]
+                tok_i = int(nxt[i])
+                req.tokens.append(tok_i)
+                self._last_tok[i] = tok_i
+                self._kv_len[i] += 1
+                if (len(req.tokens) >= req.max_tokens
+                        or self._kv_len[i] >= self.cache_len - 1):
+                    # leave at a token boundary: the slot's rows stay in
+                    # the cache, masked out by kv_len=0 until a new
+                    # admission overwrites them
+                    req.done = True
+                    finished.append(req)
+                    self.active[i] = None
+                    self._kv_len[i] = 0
+                    self._last_tok[i] = 0
 
     def _run_slot(self, max_steps: int) -> list[Request]:
         """Baseline scheduler: one jitted decode call per slot per token
@@ -362,9 +405,11 @@ class ServingEngine:
         while (any(self.active) or not self._pending.empty()) \
                 and steps < max_steps:
             self._degrade_tick()
-            for i, cache in self._admit_free_slots(finished):
+            for i, req, cache in self._prefill_polled(
+                    self._poll_free_slots(), finished):
+                self.active[i] = req
                 caches[i] = cache
-                occ[i] = len(self.active[i].prompt)
+                occ[i] = len(req.prompt)
             # lock-step decode over occupied slots
             for i, req in enumerate(self.active):
                 if req is None:
@@ -422,20 +467,3 @@ class ServingEngine:
             if ev.stage == "wait":
                 subs[ev.request_id] = ev.t_start
         return [t - subs[rid] for rid, t in seen.items() if rid in subs]
-
-    def latency_report(self, slo: TailSLO | None = None,
-                       ) -> tuple[LatencyStats, SLOReport | None]:
-        """Per-request e2e (submit -> last decode) tail percentiles.
-
-        Same LatencyStats/TailSLO machinery as the serving cluster, so
-        a single engine and an N-replica deployment report their tails
-        in the same vocabulary. Rejected requests count toward the SLO
-        drop-fraction bound, not the latency distribution.
-        """
-        e2e = self.log.end_to_end(
-            stages=["wait", "prefill", "decode"])
-        stats = LatencyStats.from_samples(e2e)
-        offered = stats.n + self.rejected
-        drop_fraction = self.rejected / offered if offered else 0.0
-        return stats, (slo.check(stats, drop_fraction)
-                       if slo is not None else None)

@@ -11,7 +11,7 @@ except ModuleNotFoundError:      # deterministic single-example shim
 
 from repro.core import acceleration as acc
 from repro.core.broker import BrokerConfig
-from repro.core.events import EventLog
+from repro.core.events import EventLog, Timer, categorize
 from repro.core.queueing import bottleneck, max_stable_speedup, utilizations
 from repro.core.simulator import (
     ClusterSim, FaceRecWorkload, object_detection_workload,
@@ -32,6 +32,79 @@ def test_event_log_breakdown_and_tax():
     tax = log.ai_tax(ai_stages={"detect", "identify"})
     assert abs(tax["ai_fraction"] - (0.07 + 0.13) / 0.35) < 1e-9
     assert abs(log.mean_e2e() - 0.35) < 1e-9
+
+
+def _per_item_span(log, rids, stage, t_start, t_end, payload_bytes=0,
+                   split_payload=False, **meta):
+    """Per-item logging of a batched span, one event per request: what
+    ``log_batch_span`` stored before batches became single records."""
+    B = max(len(rids), 1)
+    dt = (t_end - t_start) / B
+    for i, rid in enumerate(rids):
+        per = (payload_bytes // B + (payload_bytes % B if i == 0 else 0)
+               if split_payload else payload_bytes)
+        log.log(rid, stage, t_start + i * dt, t_start + (i + 1) * dt,
+                payload_bytes=per, batch_size=B, **meta)
+
+
+def _per_item_transfers(log, rids, boundary, h2d, d2h, t):
+    B = max(len(rids), 1)
+    for j, rid in enumerate(rids):
+        up, dn = (h2d % B, d2h % B) if j == 0 else (0, 0)
+        log.log_transfer(rid, "h2d", h2d // B + up, boundary, t)
+        log.log_transfer(rid, "d2h", d2h // B + dn, boundary, t)
+
+
+@pytest.mark.parametrize("split", [False, True])
+@pytest.mark.parametrize("B", [1, 3, 32])
+def test_batch_records_read_as_per_item_events(B, split, tmp_path):
+    """A batched span or crossing is stored once, and every reading of
+    the log equals what per-item logging gives."""
+    rids = list(range(100, 100 + B))
+    one, ref = EventLog(), EventLog()
+    for log, batched in ((one, True), (ref, False)):
+        span = (log.log_batch_span if batched
+                else lambda *a, **k: _per_item_span(log, *a, **k))
+        xfer = (log.log_batch_transfers if batched
+                else lambda *a, **k: _per_item_transfers(log, *a, **k))
+        log.log(rids[-1], "wait", 0.0, 0.41)
+        span(rids, "pre_admit", 0.41, 0.5, 7, split_payload=split, tick=3)
+        span(rids, "decode", 0.5, 0.83, 1001, split_payload=split, tick=3)
+        xfer(rids, "decode", h2d=8 * B + 3, d2h=4 * B + 1, t=0.83)
+        clock = iter([0.83, 0.9]).__next__
+        if batched:
+            with Timer(log, rids, "transfer", 4 * B + 1, clock=clock,
+                       direction="d2h", boundary="decode", tick=3):
+                pass
+        else:
+            t0, t1 = clock(), clock()
+            _per_item_span(log, rids, "transfer", t0, t1, 4 * B + 1,
+                           split_payload=True, kind="transfer",
+                           direction="d2h", boundary="decode", tick=3)
+    assert len(one._records) == 5
+    assert len(ref._records) == 1 + B * 5
+    fields = lambda log: [(e.request_id, e.stage, e.t_start, e.t_end,
+                           e.payload_bytes, e.meta) for e in log.events]
+    assert fields(one) == fields(ref)
+    readings = [
+        lambda log: log.breakdown(), lambda log: log.breakdown(0.9),
+        lambda log: log.five_way(categorize),
+        lambda log: log.five_way_seconds(categorize),
+        lambda log: log.windowed_five_way(categorize, 0.25),
+        lambda log: log.windowed_five_way(categorize, 0.25, fractions=False),
+        lambda log: log.transfer_bytes(),
+        lambda log: log.transfer_bytes("decode"),
+        lambda log: log.end_to_end(), lambda log: log.percentiles(),
+        lambda log: log.ai_tax({"decode"}, categorize),
+        lambda log: log.throughput()]
+    for read in readings:
+        assert read(one) == read(ref)
+    one.to_jsonl(tmp_path / "one.jsonl")
+    ref.to_jsonl(tmp_path / "ref.jsonl")
+    assert (tmp_path / "one.jsonl").read_text() == \
+        (tmp_path / "ref.jsonl").read_text()
+    with pytest.raises(AttributeError):
+        one.events.append(None)
 
 
 # ---- Amdahl (paper §5.1) ---------------------------------------------------

@@ -231,3 +231,93 @@ def test_engine_traces_once_per_kernel_impl():
             eng.submit(Request(len(impl), prompt, max_tokens=2))
             eng.run()
     assert E._step_batched_fused._cache_size() == before + 2
+
+
+PHASES = ("pre_admit", "prefill", "transfer", "pre_insert", "decode",
+          "post_tokens")
+
+
+def test_engine_tick_phases_are_tagged_and_resolve():
+    """Each continuous tick logs one span per host phase, all tagged with
+    the tick's number, in order and without overlap; every stage name
+    resolves through the canonical table."""
+    from repro.core.events import categorize
+    p = _prompts(_engine()[1], 5, 8, seed=41)
+    eng, done = _run_engine("continuous", [Request(i, p[i], max_tokens=4)
+                                           for i in range(5)])
+    assert len(done) == 5
+    by_tick = {}
+    for rec in eng.log._records:
+        if "tick" in rec.meta:
+            by_tick.setdefault(rec.meta["tick"], []).append(rec)
+    assert sorted(by_tick) == list(range(eng._tick_no))
+    for n, recs in by_tick.items():
+        assert all(r.stage in PHASES for r in recs)
+        assert all(categorize(r.stage, default=None) for r in recs)
+        assert all(a.t_end <= b.t_start for a, b in zip(recs, recs[1:]))
+        stages = [r.stage for r in recs]
+        assert stages[0] == "pre_admit"
+        if "decode" in stages:
+            assert stages[-4:] == ["transfer", "decode", "transfer",
+                                   "post_tokens"]
+            dec = recs[-3]
+            assert dec.meta["slots_busy"] == len(dec.rids)
+            assert dec.meta["queue_depth"] >= 0
+            assert [r.meta["direction"] for r in (recs[-4], recs[-2])] == \
+                ["h2d", "d2h"]
+    # the read view carries the tag on every per-request slice
+    assert {e.meta["tick"] for e in eng.log.events
+            if "tick" in e.meta} == set(by_tick)
+
+
+def test_engine_full_tick_stores_few_records():
+    """A 32-slot tick that admits nothing stores one record per phase,
+    not one event per slot and crossing; the read view still gives one
+    event per slot and phase."""
+    eng, cfg = _engine(slots=32, cache_len=32)
+    for i, prompt in enumerate(_prompts(cfg, 32, 8, seed=43)):
+        eng.submit(Request(i, prompt, max_tokens=8))
+    eng.run(max_steps=1)                       # admits all 32, decodes
+    assert all(r is not None for r in eng.active)
+    n_rec, n_ev = len(eng.log._records), len(eng.log.events)
+    eng.run(max_steps=1)
+    assert len(eng.log._records) - n_rec == 5
+    new = eng.log.events[n_ev:]
+    assert len(new) == 5 * 32
+    assert {e.request_id for e in new} == set(range(32))
+    assert eng.log.transfer_bytes()["d2h"] == eng.d2h_bytes
+
+
+def test_engine_tick_spans_nest_in_the_profiler_trace(tmp_path):
+    """Under the profiler, each tick is a step ``engine.tick`` on the
+    host line of the thread that ran it, with the phase spans inside;
+    a collection shows as ``python.gc``."""
+    import gc
+    from jax.profiler import ProfileData
+    eng, cfg = _engine(slots=2)
+    p = _prompts(cfg, 3, 8, seed=47)
+    eng.submit(Request(0, p[0], max_tokens=3))
+    eng.run()                                  # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for i in (1, 2):
+            eng.submit(Request(i, p[i], max_tokens=3))
+        eng.run()
+        gc.collect()
+    finally:
+        jax.profiler.stop_trace()
+    pb = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for e in line.events]
+             for plane in ProfileData.from_file(str(pb)).planes
+             for line in plane.lines]
+    line = next(ev for ev in lines if any(n == "engine.tick" for n, *_ in ev))
+    ticks = [(s, e) for n, s, e in line if n == "engine.tick"]
+    assert len(ticks) == 2
+    for want in ("engine.h2d.decode", "engine.decode", "engine.post_tokens",
+                 "engine.pre_admit", "engine.prefill", "engine.d2h.prefill"):
+        spans = [(s, e) for n, s, e in line if n == want]
+        assert spans, want
+        assert all(any(a <= s and e <= b for a, b in ticks)
+                   for s, e in spans), want
+    assert any(n == "python.gc" for n, *_ in line)
