@@ -201,10 +201,26 @@ def decode_attention(
     scale: float | None = None,
     impl: Impl | None = None,
     blk_k: int | None = None,
+    k_new: jax.Array | None = None,   # (B, 1, KV, D)
+    v_new: jax.Array | None = None,   # (B, 1, KV, D)
 ) -> jax.Array:
+    """One query row per batch row against its ``kv_len`` cached rows.
+
+    ``k_new``/``v_new`` are the current token's own key and value, not
+    yet written to the cache: they are attended as one more score column
+    after the cached rows (position ``kv_len``), merged in the softmax,
+    so the caller can leave the cache untouched until after the step.
+    """
     impl = _resolve(impl)
     if impl in ("pallas", "pallas_interpret"):
         from repro.kernels import decode_attention as da
+        if k_new is not None:
+            # the kernel attends cached rows only: put the token's row
+            # into a copy of the layer
+            rows = jnp.arange(q.shape[0])
+            k = k.at[rows, kv_len].set(k_new[:, 0].astype(k.dtype))
+            v = v.at[rows, kv_len].set(v_new[:, 0].astype(v.dtype))
+            kv_len = kv_len + 1
         if blk_k is None:
             from repro.kernels import autotune
             blk_k = autotune.decode_tiling(k.shape[1], q.shape[-1],
@@ -216,18 +232,37 @@ def decode_attention(
     _, L, KV, _ = k.shape
     G = H // KV
     scale = (1.0 / D**0.5) if scale is None else scale
+    # tokens attended in all: the cached rows, and the new one if given
+    n = kv_len if k_new is None else kv_len + 1
     with jax.named_scope("flashable_decode"):
-        s = jnp.einsum("bkgd,bskd->bkgs",
-                       (q[:, 0].astype(jnp.float32) * scale).reshape(B, KV, G, D),
-                       k.astype(jnp.float32))
+        qs = (q[:, 0].astype(jnp.float32) * scale).reshape(B, KV, G, D)
+        s = jnp.einsum("bkgd,bskd->bkgs", qs, k.astype(jnp.float32))
         k_pos = jnp.arange(L)[None, :]
         valid = k_pos < kv_len[:, None]
         if window is not None:
-            valid &= k_pos > (kv_len[:, None] - 1 - window)
+            valid &= k_pos > (n[:, None] - 1 - window)
         s = jnp.where(valid[:, None, None], s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bkgs,bskd->bkgd", p, v.astype(jnp.float32))
+        if k_new is None:
+            p = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bkgs,bskd->bkgd", p, v.astype(jnp.float32))
+        else:
+            s_new = jnp.einsum("bkgd,bkd->bkg", qs,
+                               k_new[:, 0].astype(jnp.float32))
+            p, p_new = softmax_with_column(s, s_new)
+            o = (jnp.einsum("bkgs,bskd->bkgd", p, v.astype(jnp.float32))
+                 + p_new[..., None] * v_new[:, 0, :, None].astype(jnp.float32))
         return o.reshape(B, 1, H, D).astype(q.dtype)
+
+
+def softmax_with_column(s: jax.Array, s_new: jax.Array):
+    """Softmax over the last axis of ``s`` with ``s_new`` (``s.shape[:-1]``)
+    as one more column: returns the probabilities of ``s``'s columns and
+    of the extra one, which sum to one."""
+    m = jnp.maximum(jnp.max(s, axis=-1), s_new)
+    e = jnp.exp(s - m[..., None])
+    e_new = jnp.exp(s_new - m)
+    den = jnp.sum(e, axis=-1) + e_new
+    return e / den[..., None], e_new / den
 
 
 # --------------------------------------------------------------------------

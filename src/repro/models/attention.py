@@ -62,10 +62,12 @@ def attn_cache_meta(cfg, spec, batch: int, cache_len: int) -> dict:
                          ("batch", "kv_seq", None), "zeros"),
                 "kr": P((batch, cache_len, m.qk_rope),
                         ("batch", "kv_seq", None), "zeros")}
+    # heads before positions: each head's (L, D) rows are one matrix,
+    # which the decode step's dots read as stored
     KV, D = cfg.n_kv_heads, cfg.head_dim
     L = min(spec.window, cache_len) if spec.window else cache_len
-    return {"k": P((batch, L, KV, D), ("batch", "kv_seq", "kv_heads", None), "zeros"),
-            "v": P((batch, L, KV, D), ("batch", "kv_seq", "kv_heads", None), "zeros")}
+    return {"k": P((batch, KV, L, D), ("batch", "kv_heads", "kv_seq", None), "zeros"),
+            "v": P((batch, KV, L, D), ("batch", "kv_heads", "kv_seq", None), "zeros")}
 
 
 # --------------------------------------------------------------------------
@@ -122,8 +124,8 @@ def attn_prefill(cfg, spec, p, x, positions, cache_len: int):
                  "v": _roll_window(v, spec.window)}
     else:
         cache = {"k": _fit(k, cache_len), "v": _fit(v, cache_len)}
-    cache = {n: shard(c, "batch", "kv_seq", "kv_heads", None)
-             if c.ndim == 4 else shard(c, "batch", "kv_seq", None)
+    cache = {n: shard(jnp.swapaxes(c, 1, 2), "batch", "kv_heads", "kv_seq",
+                      None)
              for n, c in cache.items()}
     return y, cache
 
@@ -149,19 +151,27 @@ def _roll_window(t, W):
     return out.at[:, slots].set(tail)
 
 
-def attn_decode(cfg, spec, p, x, cache, cur_len):
+def attn_decode(cfg, spec, p, x, cache, cur_len, layer):
     """One-token decode. x: (B, 1, d).
+
+    ``cache`` is the whole stack of this pattern position's caches,
+    leaves ``(n_repeats, B, KV, L, D)``, and ``layer`` indexes it: the
+    layer's rows are read where they lie, and the cache is not written
+    here. The token's own key and value are attended as one more score
+    column after the cached rows; they are returned as this layer's new
+    rows, ``(B, ...)`` per leaf, which :func:`attn_write` puts into the
+    stack once every layer has run.
 
     ``cur_len`` is the tokens-so-far count — a scalar (the classic
     lock-step cache where every row is at the same position) or a
     ``(B,)`` vector for continuous batching, where each slot of the
-    batched cache sits at its own length: positions, the cache insert,
+    batched cache sits at its own length: positions, the cache write,
     and the validity mask are then all per-row, and the ragged
     ``kv_len`` flows straight into :func:`ops.decode_attention` (the
     Pallas ragged decode kernel's contract).
     """
     if cfg.mla is not None:
-        return _mla_decode(cfg, p, x, cache, cur_len)
+        return _mla_decode(cfg, p, x, cache, cur_len, layer)
     B = x.shape[0]
     H, KV, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     ragged = jnp.ndim(cur_len) == 1
@@ -170,50 +180,77 @@ def attn_decode(cfg, spec, p, x, cache, cur_len):
     else:
         pos = jnp.full((B, 1), cur_len, jnp.int32)
     q, k, v = _project_qkv(cfg, p, x, pos)
-    L = cache["k"].shape[1]
+    # (B, L, KV, D) views of the stored (B, KV, L, D): a layout, not a copy
+    ck = shard(jnp.swapaxes(_read_layer(cache["k"], layer), 1, 2),
+               "batch", "kv_seq", "kv_heads", None)
+    cv = shard(jnp.swapaxes(_read_layer(cache["v"], layer), 1, 2),
+               "batch", "kv_seq", "kv_heads", None)
+    k, v = k.astype(ck.dtype), v.astype(cv.dtype)    # the rows as stored
+    L = ck.shape[1]
+    if spec.window:
+        # rolling cache: slot s holds position s + L*floor((t-s)/L) once
+        # the token at t = cur_len is written; before that, the slot it
+        # takes holds position t - L, which falls out of the window
+        s_idx = jnp.arange(L)
+        t = cur_len[:, None] if ragged else jnp.full((B, 1), cur_len)
+        pos_of_slot = s_idx[None] + L * ((t - s_idx[None]) // L)
+        valid = (pos_of_slot >= 0) & (pos_of_slot < t)
+        o = _masked_decode(cfg, q, ck, cv, valid, k, v)
+    else:
+        kv_len = (cur_len.astype(jnp.int32) if ragged
+                  else jnp.full((B,), cur_len, jnp.int32))
+        o = ops.decode_attention(q, ck, cv, kv_len=kv_len, k_new=k, v_new=v)
+    y = o.reshape(B, 1, H * D) @ p["wo"]
+    return y, {"k": k[:, 0], "v": v[:, 0]}
+
+
+def attn_write(spec, cache, rows, cur_len):
+    """Write every layer's new row into the stacked cache: each leaf's
+    ``rows`` ``(n_repeats, B, ...)`` go to position ``slot[b]`` of its
+    sequence axis, the second to last (``k``/``v`` ``(.., KV, L, D)``,
+    MLA's ``(.., L, C)``).
+
+    ``cur_len`` is scalar (one write over the whole batch) or ``(B,)``
+    (one write per batch row). Each is a dynamic-update-slice, which XLA
+    performs in whatever layout the cache is stored, where a scatter
+    would first re-lay the whole cache out (head_dim 64 is stored with
+    the sequence axis minor)."""
+    L = next(iter(cache.values())).shape[-2]
     slot = cur_len % L if spec.window else cur_len
     with jax.named_scope("kv_update"):
-        if ragged:
-            # per-row insert: row b writes its token at its own slot[b]
-            ck = cache["k"].at[jnp.arange(B), slot].set(k[:, 0])
-            cv = cache["v"].at[jnp.arange(B), slot].set(v[:, 0])
-        else:
-            ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot,
-                                                     axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, slot,
-                                                     axis=1)
-        ck = shard(ck, "batch", "kv_seq", "kv_heads", None)
-        cv = shard(cv, "batch", "kv_seq", "kv_heads", None)
-    if spec.window:
-        # rolling cache: slot s holds position s + L*floor((t-s)/L), t = cur_len
-        s_idx = jnp.arange(L)
-        if ragged:
-            pos_of_slot = s_idx[None] + L * ((cur_len[:, None] - s_idx[None])
-                                             // L)
-            valid = pos_of_slot >= 0
-        else:
-            pos_of_slot = s_idx + L * ((cur_len - s_idx) // L)
-            valid = (pos_of_slot >= 0)[None].repeat(B, 0)
-        o = _masked_decode(cfg, q, ck, cv, valid)
-    else:
-        kv_len = (cur_len.astype(jnp.int32) + 1 if ragged
-                  else jnp.full((B,), cur_len + 1, jnp.int32))
-        o = ops.decode_attention(q, ck, cv, kv_len=kv_len)
-    y = o.reshape(B, 1, H * D) @ p["wo"]
-    return y, {"k": ck, "v": cv}
+        return {n: _write_rows(c, rows[n], slot) for n, c in cache.items()}
 
 
-def _masked_decode(cfg, q, k, v, valid):
-    """Decode attention with an explicit (B, L) validity mask."""
+def _read_layer(stack, layer):
+    return jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+
+
+def _write_rows(stack, rows, slot):
+    rows = jnp.expand_dims(rows.astype(stack.dtype), -2)  # (R, B, .., 1, C)
+    mid = (0,) * (stack.ndim - 4)
+    if jnp.ndim(slot) == 0:
+        return jax.lax.dynamic_update_slice(stack, rows,
+                                            (0, 0) + mid + (slot, 0))
+    for b in range(rows.shape[1]):
+        stack = jax.lax.dynamic_update_slice(
+            stack, rows[:, b:b + 1], (0, b) + mid + (slot[b], 0))
+    return stack
+
+
+def _masked_decode(cfg, q, k, v, valid, k_new, v_new):
+    """Decode attention with an explicit (B, L) validity mask over the
+    cached rows, and the token's own ``k_new``/``v_new`` (B, 1, KV, D)
+    as one more column."""
     B, _, H, D = q.shape
     L, KV = k.shape[1], k.shape[2]
     G = H // KV
-    s = jnp.einsum("bkgd,bskd->bkgs",
-                   (q[:, 0].astype(jnp.float32) * D**-0.5).reshape(B, KV, G, D),
-                   k.astype(jnp.float32))
+    qs = (q[:, 0].astype(jnp.float32) * D**-0.5).reshape(B, KV, G, D)
+    s = jnp.einsum("bkgd,bskd->bkgs", qs, k.astype(jnp.float32))
     s = jnp.where(valid[:, None, None], s, ops.NEG_INF)
-    pr = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgs,bskd->bkgd", pr, v.astype(jnp.float32))
+    s_new = jnp.einsum("bkgd,bkd->bkg", qs, k_new[:, 0].astype(jnp.float32))
+    pr, pr_new = ops.softmax_with_column(s, s_new)
+    o = (jnp.einsum("bkgs,bskd->bkgd", pr, v.astype(jnp.float32))
+         + pr_new[..., None] * v_new[:, 0, :, None].astype(jnp.float32))
     return o.reshape(B, 1, H, D).astype(q.dtype)
 
 
@@ -254,11 +291,12 @@ def _mla_apply(cfg, p, x, positions):
     return y, (ckv, kr)
 
 
-def _mla_decode(cfg, p, x, cache, cur_len):
+def _mla_decode(cfg, p, x, cache, cur_len, layer):
     """Absorbed-matrix decode: attend in the 512-d latent space.
 
-    ``cur_len`` scalar (lock-step) or ``(B,)`` (ragged slots), as in
-    :func:`attn_decode`.
+    ``cur_len`` scalar (lock-step) or ``(B,)`` (ragged slots), and the
+    stacked ``cache`` read in place with the token's own latent as one
+    more column, as in :func:`attn_decode`.
     """
     m = cfg.mla
     B = x.shape[0]
@@ -269,31 +307,28 @@ def _mla_decode(cfg, p, x, cache, cur_len):
     else:
         pos = jnp.full((B, 1), cur_len, jnp.int32)
     q_nope, q_rope, ckv_t, kr_t = _mla_project(cfg, p, x, pos)
-    if ragged:
-        ckv = cache["ckv"].at[jnp.arange(B), cur_len].set(ckv_t[:, 0])
-        kr = cache["kr"].at[jnp.arange(B), cur_len].set(kr_t[:, 0])
-    else:
-        ckv = jax.lax.dynamic_update_slice_in_dim(cache["ckv"], ckv_t,
-                                                  cur_len, axis=1)
-        kr = jax.lax.dynamic_update_slice_in_dim(cache["kr"], kr_t,
-                                                 cur_len, axis=1)
-    ckv = shard(ckv, "batch", "kv_seq", None)
-    kr = shard(kr, "batch", "kv_seq", None)
+    ckv = shard(_read_layer(cache["ckv"], layer), "batch", "kv_seq", None)
+    kr = shard(_read_layer(cache["kr"], layer), "batch", "kv_seq", None)
     wkv_b = p["wkv_b"].reshape(m.kv_lora, H, m.qk_nope + m.v_head)
     wk = wkv_b[..., :m.qk_nope]            # (lora, H, nope)
     wv = wkv_b[..., m.qk_nope:]            # (lora, H, v)
     # absorb wk into q: (B,1,H,nope) x (lora,H,nope) -> (B,H,lora)
-    q_lat = jnp.einsum("bhd,lhd->bhl", q_nope[:, 0], wk)
+    q_lat = jnp.einsum("bhd,lhd->bhl", q_nope[:, 0], wk).astype(jnp.float32)
+    q_r = q_rope[:, 0].astype(jnp.float32)
     scale = (m.qk_nope + m.qk_rope) ** -0.5
-    s = (jnp.einsum("bhl,bsl->bhs", q_lat.astype(jnp.float32),
-                    ckv.astype(jnp.float32))
-         + jnp.einsum("bhr,bsr->bhs", q_rope[:, 0].astype(jnp.float32),
-                      kr.astype(jnp.float32))) * scale
+    s = (jnp.einsum("bhl,bsl->bhs", q_lat, ckv.astype(jnp.float32))
+         + jnp.einsum("bhr,bsr->bhs", q_r, kr.astype(jnp.float32))) * scale
+    # the token's own latent, as stored (cache dtype)
+    ckv_n = ckv_t[:, 0].astype(ckv.dtype).astype(jnp.float32)
+    kr_n = kr_t[:, 0].astype(kr.dtype).astype(jnp.float32)
+    s_new = (jnp.einsum("bhl,bl->bh", q_lat, ckv_n)
+             + jnp.einsum("bhr,br->bh", q_r, kr_n)) * scale
     k_pos = jnp.arange(ckv.shape[1])
     bound = cur_len[:, None, None] if ragged else cur_len
-    s = jnp.where(k_pos[None, None, :] <= bound, s, ops.NEG_INF)
-    pr = jax.nn.softmax(s, axis=-1)
-    o_lat = jnp.einsum("bhs,bsl->bhl", pr, ckv.astype(jnp.float32))   # (B,H,lora)
+    s = jnp.where(k_pos[None, None, :] < bound, s, ops.NEG_INF)
+    pr, pr_new = ops.softmax_with_column(s, s_new)
+    o_lat = (jnp.einsum("bhs,bsl->bhl", pr, ckv.astype(jnp.float32))
+             + pr_new[..., None] * ckv_n[:, None])              # (B,H,lora)
     o = jnp.einsum("bhl,lhv->bhv", o_lat.astype(x.dtype), wv)
     y = o.reshape(B, 1, H * m.v_head) @ p["wo"]
-    return y, {"ckv": ckv, "kr": kr}
+    return y, {"ckv": ckv_t[:, 0], "kr": kr_t[:, 0]}

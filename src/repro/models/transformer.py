@@ -125,15 +125,17 @@ def _apply_layer_prefill(cfg, spec, lp, x, positions, cache_len, aux):
     return x, cache, aux
 
 
-def _apply_layer_decode(cfg, spec, lp, x, cache, cur_len):
+def _apply_layer_decode(cfg, spec, lp, x, cache, cur_len, layer):
     # named scopes (``attn``/``mamba``/``rwkv``, ``moe``/``mlp``) tag the
     # ops' metadata only, so a trace viewer can tell which part of the
-    # step owns an op
+    # step owns an op. An attention layer's ``cache`` is the whole stack
+    # and it returns its new rows (see ``attn_decode``); a recurrent
+    # layer's is its own state, returned updated.
     h = apply_norm(cfg, lp["ln1"], x)
     with jax.named_scope(spec.kind):
         if spec.kind == "attn":
             mix, cache = attn.attn_decode(cfg, spec, lp["mix"], h, cache,
-                                          cur_len)
+                                          cur_len, layer)
         elif spec.kind == "mamba":
             mix, cache = ssm.mamba_decode(cfg, lp["mix"], h, cache)
         else:
@@ -252,25 +254,40 @@ def _lm_decode_blocks(cfg, params, blocks, tokens, cur_len):
 
     ``cur_len`` is scalar (lock-step) or ``(B,)`` (ragged slots); the
     attention layers handle either form (see ``attn_decode``).
+
+    The attention caches stay out of the layer scan: each layer reads its
+    rows where they lie in the stack and hands back only its new rows,
+    which are written into the stack after the scan. So the step never
+    slices a layer's block out of the stack or stacks it back. Recurrent
+    states (a few KB a row, rewritten whole each step) are scanned as
+    ``xs``/``ys``.
     """
     dtype = jnp.dtype(cfg.dtype)
     params = cast_params(params, dtype)
     x = embed_tokens(cfg, params["embed"], tokens, dtype)
+    names = [f"l{i}" for i in range(len(cfg.block_pattern))]
+    attn_pos = {n for n, spec in zip(names, cfg.block_pattern)
+                if spec.kind == "attn"}
+    rec = {n: blocks[n] for n in names if n not in attn_pos}
 
-    def block_fn(x, bp_cache):
-        bp, bc = bp_cache
+    def block_fn(x, xs):
+        bp, rc, layer = xs
         new = {}
-        for i, spec in enumerate(cfg.block_pattern):
-            x, nc = _apply_layer_decode(cfg, spec, bp[f"l{i}"], x,
-                                        bc[f"l{i}"], cur_len)
-            new[f"l{i}"] = nc
+        for n, spec in zip(names, cfg.block_pattern):
+            cache = blocks[n] if n in attn_pos else rc[n]
+            x, new[n] = _apply_layer_decode(cfg, spec, bp[n], x, cache,
+                                            cur_len, layer)
         return x, new
 
-    x, new_caches = jax.lax.scan(block_fn, x, (params["blocks"], blocks))
+    layers = jnp.arange(cfg.n_repeats, dtype=jnp.int32)
+    x, new = jax.lax.scan(block_fn, x, (params["blocks"], rec, layers))
     with jax.named_scope("lm_head"):
         x = apply_norm(cfg, params["ln_f"], x)
         logits = unembed(cfg, params["embed"], x[:, -1:])[:, 0]
-    return logits, new_caches
+    for n, spec in zip(names, cfg.block_pattern):
+        if n in attn_pos:
+            new[n] = attn.attn_write(spec, blocks[n], new[n], cur_len)
+    return logits, new
 
 
 def lm_decode_step(cfg, params, cache, tokens):

@@ -3,7 +3,8 @@
 Production concerns covered at container scale:
   * request queue with admission to fixed batch slots;
   * continuous batching (``scheduler="continuous"``, the default): ONE
-    batched KV cache of shape (slots, cache_len, ...) plus a host-side
+    batched KV cache, one slot per batch row of every layer's
+    ``(slots, KV, cache_len, D)`` block, plus a host-side
     per-slot occupancy vector, ONE jitted ragged decode step per
     scheduler tick over all occupied slots (through
     ``ops.decode_attention``, the Pallas ragged decode kernel's entry
@@ -185,7 +186,7 @@ class ServingEngine:
         # them never touches the device
         self._kv_len = np.zeros(batch_slots, np.int32)
         self._last_tok = np.zeros(batch_slots, np.int32)
-        self._blocks = None          # batched (slots, cache_len, ...) cache
+        self._blocks = None          # batched cache, one slot per batch row
         self._tick_no = 0            # continuous ticks run, for step_num
         # fast_path: greedy token selection is fused into the jitted
         # decode program, so one int32 per slot crosses device->host per

@@ -184,6 +184,33 @@ def test_decode_attention_heterogeneous_kvlen_batch():
     np.testing.assert_allclose(out[1:], want[1:], atol=3e-5, rtol=3e-5)
 
 
+@pytest.mark.parametrize("impl,window", [("xla", None), ("xla", 100),
+                                          ("pallas_interpret", None),
+                                          ("pallas_interpret", 100)])
+def test_decode_attention_new_token_column(impl, window):
+    """The token's own key and value passed as ``k_new``/``v_new`` attend
+    exactly as if written at row ``kv_len`` of the cache and attended
+    with ``kv_len + 1``: an empty slot, windows straddling a tile edge,
+    a cache one row short of full."""
+    B, L = 4, 512
+    q = _rand((B, 1, 8, 64), seed=32)
+    k = _rand((B, L, 2, 64), seed=33)
+    v = _rand((B, L, 2, 64), seed=34)
+    k_new = _rand((B, 1, 2, 64), seed=35)
+    v_new = _rand((B, 1, 2, 64), seed=36)
+    kv_len = jnp.asarray([0, 299, 137, L - 1])
+    rows = jnp.arange(B)
+    kw = dict(window=window, impl=impl)
+    if impl == "pallas_interpret":
+        kw["blk_k"] = 128
+    out = ops.decode_attention(q, k, v, kv_len=kv_len, k_new=k_new,
+                               v_new=v_new, **kw)
+    want = ops.decode_attention(q, k.at[rows, kv_len].set(k_new[:, 0]),
+                                v.at[rows, kv_len].set(v_new[:, 0]),
+                                kv_len=kv_len + 1, window=window, impl="xla")
+    np.testing.assert_allclose(out, want, atol=3e-5, rtol=3e-5)
+
+
 # --------------------------------------------------------------------------
 # linear scans
 # --------------------------------------------------------------------------

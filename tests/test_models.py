@@ -1,6 +1,7 @@
 """Per-architecture smoke tests (reduced family-preserving configs) +
 decode-vs-forward consistency — the core model-correctness invariant."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -152,3 +153,127 @@ def test_input_specs_cover_all_shapes(arch):
         assert "tokens" in specs
         for s in specs.values():
             assert all(d > 0 for d in s.shape)
+
+
+# --------------------------------------------------------------------------
+# the decode step against the formulation it replaced
+# --------------------------------------------------------------------------
+
+def _restacked_attn(cfg, spec, p, x, cache, cur_len):
+    """One attention layer as the decode step used to run it: the token's
+    row written into the layer's own cache block, then every valid row of
+    the block attended. ``cache`` is this layer's block."""
+    from repro.kernels import ops
+    from repro.models import attention as at
+    B = x.shape[0]
+    ragged = jnp.ndim(cur_len) == 1
+    pos = (cur_len[:, None] if ragged
+           else jnp.full((B, 1), cur_len)).astype(jnp.int32)
+    rows = jnp.arange(B)
+    if cfg.mla is not None:
+        m, H = cfg.mla, cfg.n_heads
+        q_nope, q_rope, ckv_t, kr_t = at._mla_project(cfg, p, x, pos)
+        slot = jnp.broadcast_to(cur_len, (B,))
+        ckv = cache["ckv"].at[rows, slot].set(ckv_t[:, 0])
+        kr = cache["kr"].at[rows, slot].set(kr_t[:, 0])
+        wkv_b = p["wkv_b"].reshape(m.kv_lora, H, m.qk_nope + m.v_head)
+        q_lat = jnp.einsum("bhd,lhd->bhl", q_nope[:, 0], wkv_b[..., :m.qk_nope])
+        s = (jnp.einsum("bhl,bsl->bhs", q_lat.astype(jnp.float32),
+                        ckv.astype(jnp.float32))
+             + jnp.einsum("bhr,bsr->bhs", q_rope[:, 0].astype(jnp.float32),
+                          kr.astype(jnp.float32))) * (m.qk_nope + m.qk_rope) ** -0.5
+        k_pos = jnp.arange(ckv.shape[1])
+        s = jnp.where(k_pos[None, None] <= slot[:, None, None], s, ops.NEG_INF)
+        o_lat = jnp.einsum("bhs,bsl->bhl", jax.nn.softmax(s, axis=-1),
+                           ckv.astype(jnp.float32))
+        o = jnp.einsum("bhl,lhv->bhv", o_lat.astype(x.dtype),
+                       wkv_b[..., m.qk_nope:])
+        return o.reshape(B, 1, -1) @ p["wo"], {"ckv": ckv, "kr": kr}
+    q, k, v = at._project_qkv(cfg, p, x, pos)
+    L = cache["k"].shape[2]                          # (B, KV, L, D)
+    slot = jnp.broadcast_to(cur_len % L if spec.window else cur_len, (B,))
+    ck = cache["k"].at[rows, :, slot].set(k[:, 0])
+    cv = cache["v"].at[rows, :, slot].set(v[:, 0])
+    K, V = jnp.swapaxes(ck, 1, 2), jnp.swapaxes(cv, 1, 2)
+    if spec.window:
+        s_idx = jnp.arange(L)[None]
+        t = jnp.broadcast_to(cur_len, (B,))[:, None]
+        valid = s_idx + L * ((t - s_idx) // L) >= 0
+        Hq, KVh, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        qs = (q[:, 0].astype(jnp.float32) * D**-0.5).reshape(B, KVh, Hq // KVh, D)
+        s = jnp.einsum("bkgd,bskd->bkgs", qs, K.astype(jnp.float32))
+        s = jnp.where(valid[:, None, None], s, ops.NEG_INF)
+        o = jnp.einsum("bkgs,bskd->bkgd", jax.nn.softmax(s, axis=-1),
+                       V.astype(jnp.float32)).astype(q.dtype)
+    else:
+        o = ops.decode_attention(q, K, V, kv_len=slot + 1)
+    return o.reshape(B, 1, -1) @ p["wo"], {"k": ck, "v": cv}
+
+
+def _restacked_decode(cfg, params, blocks, tokens, cur_len):
+    """The decode step as it was: the scan slices each layer's cache
+    block out of the stack (``xs``) and stacks the updated block back
+    (``ys``)."""
+    from repro.models.layers import (apply_norm, cast_params, embed_tokens,
+                                     mlp_apply, unembed)
+    from repro.models.moe import moe_apply
+    dtype = jnp.dtype(cfg.dtype)
+    params = cast_params(params, dtype)
+    x = embed_tokens(cfg, params["embed"], tokens, dtype)
+
+    def block_fn(x, xs):
+        bp, bc = xs
+        new = {}
+        for i, spec in enumerate(cfg.block_pattern):
+            n, lp = f"l{i}", bp[f"l{i}"]
+            if spec.kind != "attn":
+                x, new[n] = tf._apply_layer_decode(cfg, spec, lp, x, bc[n],
+                                                   cur_len, None)
+                continue
+            mix, new[n] = _restacked_attn(cfg, spec, lp["mix"],
+                                          apply_norm(cfg, lp["ln1"], x),
+                                          bc[n], cur_len)
+            x = x + mix
+            h = apply_norm(cfg, lp["ln2"], x)
+            x = x + (moe_apply(cfg, lp["mlp"], h)[0] if spec.moe
+                     else mlp_apply(cfg, lp["mlp"], h))
+        return x, new
+
+    x, new = jax.lax.scan(block_fn, x, (params["blocks"], blocks))
+    x = apply_norm(cfg, params["ln_f"], x)
+    return unembed(cfg, params["embed"], x[:, -1:])[:, 0], new
+
+
+@pytest.mark.parametrize("lengths", ["ragged", "scalar"])
+@pytest.mark.parametrize("arch", ["llama3-8b", "gemma3-12b", "deepseek-v2-236b",
+                                  "jamba-v0.1-52b", "rwkv6-3b"])
+def test_decode_step_matches_restacked_formulation(arch, lengths):
+    """Reading the stacked cache in place and writing each layer's row
+    after the scan gives the logits and caches of slicing each layer's
+    block out, writing the row into it and stacking it back: global
+    attention, a sliding window, MLA, mamba and rwkv; ragged lengths
+    from an empty slot to the cache's second-to-last row, and one
+    lock-step length."""
+    base = get_config(arch, smoke=True)
+    cfg = _no_drop(base.replace(dtype="float32",
+                                n_layers=2 * len(base.block_pattern)))
+    model = build_model(cfg)
+    params = model.init(KEY)
+    B, cache_len = 4, 16
+    blocks = model.init_cache(B, cache_len)["blocks"]
+    leaves, tree = jax.tree.flatten(blocks)
+    keys = jax.random.split(jax.random.PRNGKey(5), len(leaves))
+    blocks = tree.unflatten([jax.random.normal(k, l.shape, l.dtype)
+                             for k, l in zip(keys, leaves)])
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (B, 1), 0,
+                                cfg.vocab_size)
+    cur_len = (jnp.array([0, 5, cache_len - 2, 11], jnp.int32)
+               if lengths == "ragged" else jnp.asarray(9, jnp.int32))
+    got = jax.jit(functools.partial(tf._lm_decode_blocks, cfg))(
+        params, blocks, tokens, cur_len)
+    want = jax.jit(functools.partial(_restacked_decode, cfg))(
+        params, blocks, tokens, cur_len)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
